@@ -68,6 +68,9 @@ def main(argv=None) -> None:
                          "print the top 20 functions by cumulative time")
     args = ap.parse_args(argv)
 
+    from repro.kernels.backend import use_compile_cache
+
+    use_compile_cache()
     from . import (
         fig4_degree_dist,
         fig6_partition_methods,

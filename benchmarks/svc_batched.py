@@ -103,7 +103,7 @@ def _request(entry: dict) -> GraphRequest:
 def _unbatched_phase(svc: PartitionService, pool: list[dict]):
     """Sequential pass, dedicated compile per structure (bucketing off)."""
     server = GraphServer(
-        svc, k=K, interpret=True, bucketing=None,
+        svc, k=K, bucketing=None,
         compile_cache_entries=COMPILE_CACHE_ENTRIES, start_batcher=False,
     )
     lat: list[float] = []
@@ -124,7 +124,7 @@ def _batched_phase(svc: PartitionService, pool: list[dict], y_ref: list[np.ndarr
     lat: list[float] = []
     lock = threading.Lock()
     with GraphServer(
-        svc, k=K, interpret=True, bucketing=BucketPolicy(),
+        svc, k=K, bucketing=BucketPolicy(),
         compile_cache_entries=COMPILE_CACHE_ENTRIES,
         max_batch=MAX_BATCH, max_wait_ms=MAX_WAIT_MS,
     ) as server:

@@ -277,3 +277,28 @@ def synthetic_bipartite_graph(
     _, uniq_idx = np.unique(key, return_index=True)
     rows, cols = rows[uniq_idx], cols[uniq_idx]
     return affinity_graph_from_coo(n_rows, n_cols, rows, cols), rows, cols
+
+
+def stencil_27pt_coo(g: int) -> tuple[int, np.ndarray, np.ndarray]:
+    """HPCG's 27-point 3-D stencil on a ``g × g × g`` grid, as COO.
+
+    Row ``(ix, iy, iz)`` (id ``ix + g*(iy + g*iz)``) couples to every grid
+    point within one step along each axis, itself included — 27 non-zeros
+    per interior row, ``(3g-2)^3`` in all.  Returns ``(n, rows, cols)``
+    sorted by row, then column; values are the caller's.
+    """
+    iz, iy, ix = np.meshgrid(np.arange(g), np.arange(g), np.arange(g), indexing="ij")
+    ix, iy, iz = ix.ravel(), iy.ravel(), iz.ravel()
+    rows, cols = [], []
+    for dz in (-1, 0, 1):
+        for dy in (-1, 0, 1):
+            for dx in (-1, 0, 1):
+                jx, jy, jz = ix + dx, iy + dy, iz + dz
+                ok = ((jx >= 0) & (jx < g) & (jy >= 0) & (jy < g)
+                      & (jz >= 0) & (jz < g))
+                rows.append(np.flatnonzero(ok))
+                cols.append((jx + g * (jy + g * jz))[ok])
+    rows = np.concatenate(rows)
+    cols = np.concatenate(cols)
+    order = np.lexsort((cols, rows))
+    return g ** 3, rows[order], cols[order]

@@ -226,6 +226,48 @@ class _ChurnSetup:
     relevant: np.ndarray
 
 
+def _touched_task_mask(
+    u: np.ndarray,
+    v: np.ndarray,
+    touched: np.ndarray,
+    n: int,
+    dirty_degree_cap: int | None,
+) -> np.ndarray:
+    """Tasks with an endpoint in ``touched`` whose degree is at most the cap
+    (default ``max(16, 4 * average degree)``) — the incremental dirty set."""
+    if not touched.size:
+        return np.zeros(u.shape[0], dtype=bool)
+    if dirty_degree_cap is None:
+        dirty_degree_cap = max(16, int(4 * 2.0 * u.shape[0] / max(n, 1)))
+    deg = np.bincount(np.concatenate([u, v]), minlength=max(n, 1))
+    is_touched = np.zeros(max(n, 1), dtype=bool)
+    is_touched[touched[deg[touched] <= dirty_degree_cap]] = True
+    return is_touched[u] | is_touched[v]
+
+
+def _incremental_cells_per_task(
+    edges: EdgeList,
+    insert_u: np.ndarray,
+    insert_v: np.ndarray,
+    delete_ids: np.ndarray,
+    k: int,
+) -> float:
+    """Scoring cells per task of one incremental refine pass: the dirty set
+    (counted on the base graph, plus the inserted tasks) times ``k``, over
+    the same tasks — so it never exceeds ``k``."""
+    insert_u = np.asarray(insert_u, dtype=np.int64)
+    insert_v = np.asarray(insert_v, dtype=np.int64)
+    delete_ids = np.asarray(delete_ids, dtype=np.int64)
+    # Ids out of range are left to the gear itself, which rejects them.
+    delete_ids = delete_ids[(delete_ids >= 0) & (delete_ids < edges.m)]
+    touched = np.unique(np.concatenate([
+        insert_u, insert_v, edges.u[delete_ids], edges.v[delete_ids],
+    ]).astype(np.int64))
+    n = max(edges.n, int(touched.max(initial=-1)) + 1)
+    dirty = int(_touched_task_mask(edges.u, edges.v, touched, n, None).sum())
+    return (dirty + insert_u.size) * k / max(edges.m + insert_u.size, 1)
+
+
 def _churn_setup(
     edges: EdgeList,
     labels: np.ndarray,
@@ -302,18 +344,8 @@ def _churn_setup(
             dirty_idx=np.empty(0, dtype=np.int64),
             relevant=np.zeros(0, dtype=bool),
         )
-    if dirty_degree_cap is None:
-        avg_deg = 2.0 * m_new / max(n, 1)
-        dirty_degree_cap = max(16, int(4 * avg_deg))
     t_arr = np.unique(np.concatenate(touched))
-    if t_arr.size:
-        deg = np.bincount(np.concatenate([u_all, v_all]), minlength=max(n, 1))
-        t_capped = t_arr[deg[t_arr] <= dirty_degree_cap]
-        is_touched = np.zeros(max(n, 1), dtype=bool)
-        is_touched[t_capped] = True
-        dirty_mask = is_touched[u_all] | is_touched[v_all]
-    else:
-        dirty_mask = np.zeros(m_new, dtype=bool)
+    dirty_mask = _touched_task_mask(u_all, v_all, t_arr, n, dirty_degree_cap)
     dirty_mask[n_kept:] = True  # inserted tasks always refine
     dirty_idx = np.flatnonzero(dirty_mask)
 
@@ -370,6 +402,19 @@ def _incremental_stats(
     )
 
 
+#: Cells of one (rows, k) scoring block in the batched incremental gear.
+#: Scoring every dirty task against every part at once is O(rows * k)
+#: memory — 30 GB for a 6.9M-nnz stencil at k=1024 — so the rows are
+#: scored in blocks; each row's result does not depend on the blocking.
+_SCORE_BLOCK_CELLS = 1 << 22
+
+
+def _row_chunks(n_rows: int, k: int):
+    step = max(1, _SCORE_BLOCK_CELLS // max(k, 1))
+    for s in range(0, n_rows, step):
+        yield slice(s, min(s + step, n_rows))
+
+
 def _place_insertions_batched(
     insert_u: np.ndarray,
     insert_v: np.ndarray,
@@ -401,14 +446,18 @@ def _place_insertions_batched(
     huge = np.int64(3) * w1
     part_ids = np.arange(k, dtype=np.int64)
     while pend.size:
-        iu, iv = insert_u[pend], insert_v[pend]
-        tu, tv = table[rel_of[iu]], table[rel_of[iv]]
-        loop = iu == iv
-        delta = (tu == 0).astype(np.int64) + ((~loop)[:, None] & (tv == 0))
-        score = delta * w1 + sizes * np.int64(k) + part_ids
-        score[:, sizes + 1 > cap] = huge
-        claimed = np.argmin(score, axis=1)
-        forced = score[np.arange(pend.size), claimed] >= huge
+        claimed = np.empty(pend.size, dtype=np.int64)
+        forced = np.empty(pend.size, dtype=bool)
+        full = sizes + 1 > cap
+        for sl in _row_chunks(pend.size, k):
+            iu, iv = insert_u[pend[sl]], insert_v[pend[sl]]
+            tu, tv = table[rel_of[iu]], table[rel_of[iv]]
+            loop = iu == iv
+            delta = (tu == 0).astype(np.int64) + ((~loop)[:, None] & (tv == 0))
+            score = delta * w1 + sizes * np.int64(k) + part_ids
+            score[:, full] = huge
+            claimed[sl] = best = np.argmin(score, axis=1)
+            forced[sl] = score[np.arange(best.size), best] >= huge
         if forced.any():  # no part under the cap — unreachable by the cap
             claimed[forced] = np.argmin(sizes)  # construction; kept as a valve
         order = np.argsort(claimed, kind="stable")  # pending order within part
@@ -469,22 +518,24 @@ def _refine_dirty_batched(
     du, dv = u_all[de], v_all[de]
     ru, rv = rel_of[du], rel_of[dv]
     loop = du == dv
-    notloop_col = (~loop)[:, None]
-    rows = np.arange(de.size)
     neg = np.int64(-100)  # sentinel far below any real gain (range [-2, 2])
+    best_b = np.empty(de.size, dtype=np.int64)
+    best_gain = np.empty(de.size, dtype=np.int64)
     for _ in range(passes):
         passes_run += 1
         a = labels_all[de]
-        tu, tv = table[ru], table[rv]
-        freed = (tu[rows, a] == 1).astype(np.int64)
-        freed += (~loop) & (tv[rows, a] == 1)
-        gain = freed[:, None] - ((tu == 0).astype(np.int64) + (notloop_col & (tv == 0)))
-        gain[rows, a] = neg
         full = sizes + 1 > cap
-        if full.any():
-            gain[:, full] = neg
-        best_b = np.argmax(gain, axis=1)
-        best_gain = gain[rows, best_b]
+        for sl in _row_chunks(de.size, k):
+            tu, tv, a_s, nl = table[ru[sl]], table[rv[sl]], a[sl], ~loop[sl]
+            rows = np.arange(a_s.size)
+            freed = (tu[rows, a_s] == 1).astype(np.int64)
+            freed += nl & (tv[rows, a_s] == 1)
+            gain = freed[:, None] - ((tu == 0).astype(np.int64) + (nl[:, None] & (tv == 0)))
+            gain[rows, a_s] = neg
+            if full.any():
+                gain[:, full] = neg
+            best_b[sl] = best = np.argmax(gain, axis=1)
+            best_gain[sl] = gain[rows, best]
         over_row = (sizes > cap)[a]
         cand = np.flatnonzero((best_gain > 0) | (over_row & (best_gain > neg // 2)))
         if cand.size == 0:
@@ -987,6 +1038,17 @@ def local_repartition(
 # ---------------------------------------------------------------------------
 
 
+#: Work budget of the incremental gear, in scoring cells (dirty tasks x k)
+#: per task.  Each refine pass scores every dirty task against all k parts,
+#: and a uniform batch dirties every task around a touched vertex: on the
+#: 64^3 stencil at k=1024 (6.86M tasks) a uniform 1% batch dirtied 65% of
+#: the tasks (663 cells/task) and took about 700 s against a 53.6 s
+#: rebuild, a one-slab 1% batch (26 cells/task) 21.8 s, so the break-even
+#: lies near 50-65.  Past the budget the update goes to the local gear.
+#: Every k <= 64 stays under it, so there drift alone decides.
+INCREMENTAL_MAX_CELLS_PER_TASK = 64
+
+
 @dataclasses.dataclass(frozen=True)
 class GearPolicy:
     """Drift-gated selection among the three update gears.
@@ -1035,8 +1097,12 @@ class GearPolicy:
         if self.halo_hops < 0:
             raise ValueError(f"halo_hops must be >= 0, got {self.halo_hops}")
 
-    def pick(self, drift: float) -> str:
-        if drift <= self.incremental_max_drift:
+    def pick(self, drift: float, cells_per_task: float) -> str:
+        """The gear for an update of estimated ``drift`` whose incremental
+        refine pass would score ``cells_per_task`` cells per task (see
+        :data:`INCREMENTAL_MAX_CELLS_PER_TASK`)."""
+        if (drift <= self.incremental_max_drift
+                and cells_per_task <= INCREMENTAL_MAX_CELLS_PER_TASK):
             return "incremental"
         if drift <= self.local_max_drift:
             return "local"
@@ -1278,7 +1344,8 @@ def _update_plan_job(req: _UpdateRequest) -> tuple[ServicePlan, dict]:
     # before the field existed, via getattr) plus this batch's churn
     # fraction — a stream of small batches escalates like one big batch.
     drift_est = float(getattr(base, "drift", 0.0)) + n_churn / m_new_est
-    gear = policy.pick(drift_est)
+    cells = _incremental_cells_per_task(base.edges, insert_u, insert_v, delete_ids, req.k)
+    gear = policy.pick(drift_est, cells)
     if gear == "local" and req.method != "ep":
         # The local V-cycle runs on the method-"ep" task graph (node == task);
         # other methods have no such identification, so they skip the gear.

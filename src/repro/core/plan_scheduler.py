@@ -86,6 +86,22 @@ def _pin_worker_blas_env() -> None:
         os.environ.setdefault(var, "1")
 
 
+def _cpu_only_child() -> None:
+    """Pool initializer: hold a partitioning child to JAX's CPU backend.
+
+    The jobs are host-side numpy; the accelerator belongs to the serving
+    parent, and a child that reached for it would fail or hang.  The env
+    covers a JAX imported later; ``jax.config`` covers one imported while
+    the child unpickled its work."""
+    import os
+    import sys
+
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
+
+
 # Log-2 latency buckets for the exported histograms (seconds).
 _BUCKET_EDGES_S = tuple(2.0**e for e in range(-10, 5))  # ~1 ms .. 16 s
 
@@ -323,7 +339,8 @@ class PlanScheduler:
                 # "spawn", not "fork": the parent may hold jax/BLAS threads
                 # whose locks a forked child would inherit mid-flight.
                 _pin_worker_blas_env()
-                self._pool = mp.get_context("spawn").Pool(self.workers)
+                self._pool = mp.get_context("spawn").Pool(
+                    self.workers, initializer=_cpu_only_child)
             missing = self.workers - len([t for t in self._threads if t.is_alive()])
             for i in range(missing):
                 t = threading.Thread(

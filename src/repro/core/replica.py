@@ -51,8 +51,8 @@ from typing import Any, Callable, Optional, Sequence
 
 import numpy as np
 
-from ..runtime.fault import CircuitBreaker, HeartbeatRegistry, OverloadSchedule
 from .graph import EdgeList, affinity_graph_from_coo
+from .health import CircuitBreaker, HeartbeatRegistry, OverloadSchedule
 from .partition import MultilevelOptions
 from .partition_service import (
     DoubleBuffer,

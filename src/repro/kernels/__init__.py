@@ -3,8 +3,9 @@
 The paper's optimized kernel is SpMV with cluster-local caching (§5.2);
 ``ep_spmv`` is its TPU-native form.  ``moe_mlp`` is the grouped expert FFN
 fed by EP-scheduled MoE dispatch (the technique's application to the
-assigned MoE architectures).  Pure-jnp oracles live in ``ref.py``; kernels
-are validated in interpret mode on CPU and target TPU via Mosaic.
+assigned MoE architectures).  Pure-jnp oracles live in ``ref.py``.  The
+backend decides how a kernel runs (``backend.py``): interpreted on the CPU,
+where the tests run, and lowered through Mosaic on a TPU.
 
 The model zoo / dry-run path stays pure JAX: Mosaic custom calls neither
 compile on the CPU backend nor contribute FLOPs to ``cost_analysis()``,

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import backend as _backend
+
 __all__ = ["flash_attention"]
 
 
@@ -82,7 +84,6 @@ def flash_attention(
     causal: bool = True,
     q_block: int = 128,
     kv_chunk: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     b, h, s, dh = q.shape
     t = k.shape[2]
@@ -107,6 +108,6 @@ def flash_attention(
         ],
         out_specs=pl.BlockSpec((1, q_block, dh), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, dh), q.dtype),
-        interpret=interpret,
+        interpret=_backend.pallas_interpret(),
     )(qf, kf, vf)
     return out.reshape(b, h, s, dh)

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import backend as _backend
+
 __all__ = ["moe_mlp"]
 
 
@@ -41,7 +43,6 @@ def moe_mlp(
     w_down: jax.Array,    # (n_experts, d_ff, d_model)
     *,
     tm: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """SwiGLU expert FFN over packed per-expert token tiles."""
     n_experts, capacity, d_model = x_packed.shape
@@ -62,5 +63,5 @@ def moe_mlp(
         ],
         out_specs=pl.BlockSpec((1, tm, d_model), lambda e, t: (e, t, 0)),
         out_shape=jax.ShapeDtypeStruct(x_packed.shape, x_packed.dtype),
-        interpret=interpret,
+        interpret=_backend.pallas_interpret(),
     )(x_packed, w_gate, w_up, w_down)

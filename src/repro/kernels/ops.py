@@ -11,14 +11,14 @@
                  are summed here).
 
 ``mode="software"`` stages x tiles in VMEM (shared-memory analogue);
-``mode="streaming"`` gathers from the full x inside the kernel (texture
+``mode="streaming"`` loads each task's x entry from the full x (texture
 analogue, skips step 1's relayout).
 
 Two compilation contracts coexist:
 
-* **Per-plan** (``make_ep_spmv_fn``) — the plan's padded indices are baked
-  into the trace as constants; one compile per (structure, values).  Right
-  for a few long-lived matrices, fatal for thousands of small ones.
+* **Per-plan** (``make_ep_spmv_fn``) — the plan's own tiles, uploaded once
+  when bound; one compile per bound plan.  Right for a few long-lived
+  matrices, fatal for thousands of small ones.
 * **Bucketed** (``BucketSpec`` + ``pad_plan_operands`` +
   ``make_bucketed_spmv_fn``) — the plan arrays are *arguments* of a kernel
   compiled once per shape bucket, so every request whose plan fits the
@@ -85,6 +85,10 @@ class BucketSpec:
     batch: int  # fixed micro-batch width; short batches are zero-padded
     mode: str = "software"
 
+    def __post_init__(self) -> None:
+        if self.mode not in ("software", "streaming"):
+            raise ValueError(f"unknown mode {self.mode!r}")
+
     def fits(self, plan: PackPlan) -> bool:
         """True when ``plan``'s padded tiles fit inside this bucket."""
         return (
@@ -142,7 +146,34 @@ def pad_plan_operands(
     return vp, xl, yl, xg, yg
 
 
-def make_bucketed_spmv_fn(spec: BucketSpec, interpret: bool = True):
+def _spmv_batch(spec: BucketSpec, vals, x_lidx, y_lidx, x_gidx, y_gidx, x):
+    """pack -> kernel -> combine for ``spec.batch`` batch-leading requests."""
+    b, k = spec.batch, spec.k
+    e_max, x_max, y_max = spec.e_max, spec.x_max, spec.y_max
+    n_rows = spec.n_rows
+    if spec.mode == "software":
+        # pack: each request gathers its unique x entries (n_touched + C loads)
+        x_packed = jax.vmap(lambda xg, xv: jnp.take(xv, xg, axis=0))(x_gidx, x)
+        partials = _spmv.spmv_software_cache(
+            vals.reshape(b * k, e_max),
+            x_lidx.reshape(b * k, e_max),
+            y_lidx.reshape(b * k, e_max),
+            x_packed.reshape(b * k, x_max),
+            y_max,
+        ).reshape(b, k, y_max)
+    else:
+        # Global x index per task = x_gidx[b, p, x_lidx[b, p, e]].
+        xg_task = jnp.take_along_axis(x_gidx, x_lidx, axis=2)
+        partials = _spmv.spmv_streaming_batched(vals, xg_task, y_lidx, x, y_max)
+    # combine: one flat scatter-add over the whole batch; request b's rows
+    # live at offset b * (n_rows + 1) and the sentinel row is sliced off.
+    offs = (jnp.arange(b, dtype=y_gidx.dtype) * (n_rows + 1))[:, None, None]
+    y = jnp.zeros(b * (n_rows + 1), dtype=partials.dtype)
+    y = y.at[(y_gidx + offs).reshape(-1)].add(partials.reshape(-1))
+    return y.reshape(b, n_rows + 1)[:, :n_rows]
+
+
+def make_bucketed_spmv_fn(spec: BucketSpec):
     """Compile-once kernel for a shape bucket: ``(plan arrays, x) -> y``.
 
     Unlike :func:`make_ep_spmv_fn`, nothing about the matrix is baked into
@@ -158,49 +189,13 @@ def make_bucketed_spmv_fn(spec: BucketSpec, interpret: bool = True):
     short micro-batches and de-pad each row to its request's true
     ``n_rows`` on the way out.
     """
-    b, k = spec.batch, spec.k
-    e_max, x_max, y_max = spec.e_max, spec.x_max, spec.y_max
-    n_rows = spec.n_rows
+    return jax.jit(functools.partial(_spmv_batch, spec))
 
-    if spec.mode == "software":
 
-        @jax.jit
-        def run(vals, x_lidx, y_lidx, x_gidx, y_gidx, x):
-            # pack: each request gathers its unique x entries (n_touched + C loads)
-            x_packed = jax.vmap(lambda xg, xv: jnp.take(xv, xg, axis=0))(x_gidx, x)
-            partials = _spmv.spmv_software_cache(
-                vals.reshape(b * k, e_max),
-                x_lidx.reshape(b * k, e_max),
-                y_lidx.reshape(b * k, e_max),
-                x_packed.reshape(b * k, x_max),
-                y_max,
-                interpret=interpret,
-            ).reshape(b, k, y_max)
-            return _combine(partials, y_gidx)
-
-    elif spec.mode == "streaming":
-
-        @jax.jit
-        def run(vals, x_lidx, y_lidx, x_gidx, y_gidx, x):
-            # Global x index per task = x_gidx[b, p, x_lidx[b, p, e]].
-            xg_task = jnp.take_along_axis(x_gidx, x_lidx, axis=2)
-            partials = _spmv.spmv_streaming_batched(
-                vals, xg_task, y_lidx, x, y_max, interpret=interpret
-            )
-            return _combine(partials, y_gidx)
-
-    else:
-        raise ValueError(f"unknown mode {spec.mode!r}")
-
-    def _combine(partials, y_gidx):
-        # One flat scatter-add over the whole batch: request b's rows live
-        # at offset b * (n_rows + 1); the sentinel row is sliced off.
-        offs = (jnp.arange(b, dtype=y_gidx.dtype) * (n_rows + 1))[:, None, None]
-        y = jnp.zeros(b * (n_rows + 1), dtype=partials.dtype)
-        y = y.at[(y_gidx + offs).reshape(-1)].add(partials.reshape(-1))
-        return y.reshape(b, n_rows + 1)[:, :n_rows]
-
-    return run
+def _spmv_plan(spec: BucketSpec, vals, x_lidx, y_lidx, x_gidx, y_gidx, x):
+    """One plan's ``(k, ·)`` operands and ``x (n_cols,)`` -> ``y (n_rows,)``."""
+    ops = (a[None] for a in (vals, x_lidx, y_lidx, x_gidx, y_gidx, x))
+    return _spmv_batch(spec, *ops)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -231,7 +226,6 @@ def make_ep_spmv_fn(
     plan: PackPlan,
     vals: np.ndarray,
     mode: Literal["software", "streaming"] = "software",
-    interpret: bool = True,
     timeout: float | None = None,
 ):
     """Bind a PackPlan + matrix values; return jit'd ``x -> y``.
@@ -244,12 +238,13 @@ def make_ep_spmv_fn(
     callers keep working.  The ``timeout`` kwarg only ever applied to that
     deprecated ticket wait and is deprecated with it.
 
-    The plan and packed indices are host-side constants (they change only
-    when the matrix/partition changes — per paper §4 the relayout happens
-    once, asynchronously); the returned function is the steady-state kernel
-    the accelerator runs every iteration.  For many small matrices, prefer
-    the bucketed contract (:func:`make_bucketed_spmv_fn`): this per-plan
-    form pays one fresh trace/compile per structure.
+    The packed values and indices go to the device once, here (they change
+    only when the matrix/partition changes — per paper §4 the relayout
+    happens once, asynchronously); the returned function is the
+    steady-state kernel the accelerator runs every iteration.  For many
+    small matrices, prefer the bucketed contract
+    (:func:`make_bucketed_spmv_fn`): this per-plan form compiles once per
+    bound plan.
     """
     if timeout is not None:
         warnings.warn(
@@ -269,40 +264,16 @@ def make_ep_spmv_fn(
         from ..runtime.request import resolve_plan as _resolve  # lazy: layering
 
         plan = _resolve(plan, timeout)
-    vals_packed = jnp.asarray(plan.pack_values(np.asarray(vals)))
-    x_lidx = jnp.asarray(plan.x_lidx)
-    y_lidx = jnp.asarray(plan.y_lidx)
-    x_gidx = jnp.asarray(plan.x_gidx)          # (k, X_max)
-    y_gidx = jnp.asarray(plan.y_gidx)          # (k, Y_max), n_rows = sentinel
-    n_rows, y_max = plan.n_rows, plan.y_max
-
-    if mode == "software":
-
-        @jax.jit
-        def run(x):
-            x_packed = jnp.take(x, x_gidx, axis=0)  # pack: n_touched + C loads
-            partials = _spmv.spmv_software_cache(
-                vals_packed, x_lidx, y_lidx, x_packed, y_max, interpret=interpret
-            )
-            y = jnp.zeros(n_rows + 1, dtype=partials.dtype)
-            return y.at[y_gidx.reshape(-1)].add(partials.reshape(-1))[:n_rows]
-
-    elif mode == "streaming":
-        # Global x index per task = x_gidx[p, x_lidx[p, e]].
-        xg_task = jnp.take_along_axis(x_gidx, x_lidx, axis=1)
-
-        @jax.jit
-        def run(x):
-            partials = _spmv.spmv_streaming(
-                vals_packed, xg_task, y_lidx, x, y_max, interpret=interpret
-            )
-            y = jnp.zeros(n_rows + 1, dtype=partials.dtype)
-            return y.at[y_gidx.reshape(-1)].add(partials.reshape(-1))[:n_rows]
-
-    else:
-        raise ValueError(f"unknown mode {mode!r}")
-
-    return run
+    # The plan's own tiles as a batch-of-1 bucket: the same kernel and
+    # combine as the bucketed path, with the packed operands uploaded once
+    # and passed as arguments (never folded into the program as constants).
+    # Each bound plan gets its own jit, so its executable lives and dies
+    # with the returned function (and with a compile-cache entry holding it).
+    spec = BucketSpec(k=plan.k, n_rows=plan.n_rows, n_cols=plan.n_cols,
+                      e_max=plan.e_max, x_max=plan.x_max, y_max=plan.y_max,
+                      batch=1, mode=mode)
+    operands = [jnp.asarray(a) for a in pad_plan_operands(plan, np.asarray(vals), spec)]
+    return functools.partial(jax.jit(functools.partial(_spmv_plan, spec)), *operands)
 
 
 def ep_spmv(
@@ -310,10 +281,9 @@ def ep_spmv(
     plan: PackPlan,
     vals: np.ndarray,
     mode: Literal["software", "streaming"] = "software",
-    interpret: bool = True,
 ) -> jax.Array:
     """One-shot convenience wrapper (rebinds the plan every call)."""
-    return make_ep_spmv_fn(plan, vals, mode, interpret)(x)
+    return make_ep_spmv_fn(plan, vals, mode)(x)
 
 
 def spmv_hbm_traffic_model(plan: PackPlan, mode: str = "software") -> dict:

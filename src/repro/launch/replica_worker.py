@@ -142,6 +142,19 @@ def _src_root() -> str:
     return os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
 
 
+def worker_env() -> dict:
+    """The worker's environment: the parent's, with ``src`` importable and
+    JAX held to the CPU.  A worker partitions on the host; the chip belongs
+    to the serving parent, and a second process reaching for it would fail
+    or hang."""
+    env = dict(os.environ)
+    src = _src_root()
+    env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
+                         if env.get("PYTHONPATH") else src)
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def spawn_worker(
     *,
     stalls: Sequence[tuple[float, int, int]] = (),
@@ -165,12 +178,8 @@ def spawn_worker(
         cmd += ["--persist-path", persist_path]
     for delay, first, last in stalls:
         cmd += ["--stall", f"{delay}:{first}:{last}"]
-    env = dict(os.environ)
-    src = _src_root()
-    env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
-                         if env.get("PYTHONPATH") else src)
     proc = subprocess.Popen(cmd, stdin=subprocess.PIPE,
-                            stdout=subprocess.PIPE, env=env)
+                            stdout=subprocess.PIPE, env=worker_env())
     deadline = time.monotonic() + startup_timeout_s
     line = ""
     try:

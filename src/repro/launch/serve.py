@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import get_config
+from ..kernels.backend import use_compile_cache
 from ..models import Model
 from ..runtime import (
     GraphRequest,
@@ -168,7 +169,7 @@ def run_graph_serving(
     vals = rng.standard_normal(rows.shape[0]).astype(np.float32)
 
     with PartitionService() as svc:
-        server = GraphServer(svc, k=k, pad=pad, interpret=True, start_batcher=False)
+        server = GraphServer(svc, k=k, pad=pad, start_batcher=False)
 
         def serve_once(x):
             return server.serve(GraphRequest(n_rows, n_cols, rows, cols, vals, x))
@@ -216,7 +217,7 @@ def run_graph_serving(
         vals_new = np.concatenate(
             [np.delete(vals, delete_ids), rng.standard_normal(n_churn).astype(np.float32)]
         )
-        fn = make_ep_spmv_fn(new_plan.plan, vals_new, interpret=True)
+        fn = make_ep_spmv_fn(new_plan.plan, vals_new)
         t0 = time.perf_counter()
         fn(jnp.asarray(rng.standard_normal(n_cols)))
         post_swap_s = time.perf_counter() - t0
@@ -265,7 +266,7 @@ def run_multitenant_graph_serving(
     budget = int(cache_budget_mb * 1e6)
     rng = np.random.default_rng(seed)
     with PartitionService(workers=workers, default_tenant_budget=budget) as svc:
-        server = GraphServer(svc, k=k, pad=pad, interpret=True, start_batcher=False)
+        server = GraphServer(svc, k=k, pad=pad, start_batcher=False)
 
         def serve(n_rows, n_cols, rows, cols, vals, x, tenant):
             res = server.serve(
@@ -347,7 +348,7 @@ def run_batched_graph_serving(
 
     with PartitionService(max_entries=graphs + 8) as svc:
         with GraphServer(
-            svc, k=k, pad=pad, interpret=True,
+            svc, k=k, pad=pad,
             max_batch=max_batch, max_wait_ms=max_wait_ms,
         ) as server:
             # Warm the plan cache so the measured phase is serving, not
@@ -442,8 +443,7 @@ def run_replicated_graph_serving(
     else:
         group_cm = ReplicaGroup(replicas)
     with group_cm as group:
-        server = GraphServer(group, k=k, pad=pad, interpret=True,
-                             start_batcher=False)
+        server = GraphServer(group, k=k, pad=pad, start_batcher=False)
         killed = None
         per_request = []
         t_all = time.perf_counter()
@@ -523,8 +523,7 @@ def run_overload_graph_serving(
         breaker_cooldown_s=0.2,
         max_queue_depth=queue_bound,
     ) as group:
-        server = GraphServer(group, k=k, pad=pad, interpret=True,
-                             start_batcher=False,
+        server = GraphServer(group, k=k, pad=pad, start_batcher=False,
                              brownout_hedge_off=2, brownout_stale_only=4,
                              brownout_window_s=2.0)
 
@@ -648,6 +647,7 @@ def main(argv=None):
                          "process per replica behind the TCP transport "
                          "and the mid-stream kill becomes a real SIGKILL")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.graph and args.overload:
         report = run_overload_graph_serving(queue_bound=args.queue_bound,
                                             k=args.k)

@@ -91,11 +91,7 @@ def _dispatch_shard_map():
         return None
     tp = tuple(prof.logical.get("model", ()))
     tp = tuple(a for a in tp if a in prof.mesh.shape)
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    return _sm, dp, (tp[0] if tp else None), prof.mesh
+    return jax.shard_map, dp, (tp[0] if tp else None), prof.mesh
 
 
 def _dispatch_scatter(ids_s, pos_c, x_rep, n_experts: int, cap_l: int):

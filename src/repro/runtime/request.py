@@ -434,7 +434,6 @@ class GraphServer:
         k: int,
         pad: int = 128,
         mode: str = "software",
-        interpret: bool = True,
         tenant: str = "default",
         priority: int = 0,
         bucketing: Optional[BucketPolicy] = BucketPolicy(),
@@ -451,7 +450,6 @@ class GraphServer:
         self.k = k
         self.pad = pad
         self.mode = mode
-        self.interpret = interpret
         self.tenant = tenant
         self.priority = priority
         self.bucketing = bucketing
@@ -629,7 +627,7 @@ class GraphServer:
         return self.compile_cache.get_or_build(
             ("bucket", label),
             spec.operand_elems(),
-            lambda: make_bucketed_spmv_fn(spec, interpret=self.interpret),
+            lambda: make_bucketed_spmv_fn(spec),
         )
 
     def _dedicated_fn(self, req: GraphRequest, sp: ServicePlan):
@@ -638,8 +636,7 @@ class GraphServer:
         return self.compile_cache.get_or_build(
             ("dedicated", sp.fingerprint, req.vals_digest()),
             size,
-            lambda: make_ep_spmv_fn(plan, req.vals, mode=self.mode,
-                                    interpret=self.interpret),
+            lambda: make_ep_spmv_fn(plan, req.vals, mode=self.mode),
         )
 
     def _record_batch(self, size: int) -> None:

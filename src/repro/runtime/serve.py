@@ -50,7 +50,6 @@ def make_graph_serve_fn(
     k: int,
     pad: int = 128,
     mode: str = "software",
-    interpret: bool = True,
     tenant: str = "default",
     priority: int = 0,
 ):
@@ -80,7 +79,6 @@ def make_graph_serve_fn(
         k,
         pad=pad,
         mode=mode,
-        interpret=interpret,
         tenant=tenant,
         priority=priority,
         start_batcher=False,

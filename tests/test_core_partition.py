@@ -14,6 +14,7 @@ from repro.core import (
     partition_vertices,
     parts_per_vertex,
     reconstruct_edge_partition,
+    stencil_27pt_coo,
     synthetic_banded_graph,
     synthetic_bipartite_graph,
     synthetic_mesh_graph,
@@ -57,6 +58,20 @@ class TestGraph:
     def test_self_loops_dropped(self):
         g = csr_from_edges(3, np.array([0, 1]), np.array([0, 2]))
         assert g.nnz == 2
+
+    @pytest.mark.parametrize("g", [1, 2, 5])
+    def test_stencil_27pt(self, g):
+        n, rows, cols = stencil_27pt_coo(g)
+        assert n == g**3
+        assert rows.size == (3 * g - 2) ** 3
+        assert np.all(np.diff(rows * n + cols) > 0)  # row-major, no duplicates
+        # Symmetric, with the diagonal, and every coupling within one step.
+        assert set(zip(rows.tolist(), cols.tolist())) == set(zip(cols.tolist(), rows.tolist()))
+        assert np.count_nonzero(rows == cols) == n
+        pr = np.stack(np.unravel_index(rows, (g, g, g)))
+        pc = np.stack(np.unravel_index(cols, (g, g, g)))
+        assert np.abs(pr - pc).max() <= 1
+        assert np.bincount(rows, minlength=n).max() == min(3, g) ** 3
 
     def test_affinity_from_coo_bipartite(self):
         e = affinity_graph_from_coo(3, 4, rows=np.array([0, 1, 2]), cols=np.array([1, 1, 3]))

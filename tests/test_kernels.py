@@ -1,4 +1,5 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -24,6 +25,7 @@ class TestEpSpmv:
         (64, 64, 4, 4),
         (128, 96, 3, 8),
         (33, 47, 5, 3),   # ragged, non-power-of-2
+        (1100, 1100, 3, 1),  # x/y tiles wider than one 1024-lane slab
     ])
     @pytest.mark.parametrize("mode", ["software", "streaming"])
     def test_matches_coo_ref(self, n_rows, n_cols, nnz, k, mode):
@@ -47,6 +49,24 @@ class TestEpSpmv:
         y1 = fn(jnp.asarray(x))
         y2 = fn(jnp.asarray(x * 2))
         np.testing.assert_allclose(np.asarray(y2), 2 * np.asarray(y1), rtol=1e-5)
+
+    def test_each_bound_plan_owns_its_executable(self):
+        # Two bindings of one tile shape compile twice: a binding's program
+        # is dropped with it (a bounded compile cache bounds executables).
+        plan, rows, cols, vals, x = _spmv_problem(64, 64, 4, 4)
+        compiles = []
+
+        def listener(event, duration, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(duration)
+
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        try:
+            for scale in (1.0, 2.0):
+                make_ep_spmv_fn(plan, vals * scale)(jnp.asarray(x)).block_until_ready()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listener)
+        assert len(compiles) == 2
 
 
 class TestMoeMlp:

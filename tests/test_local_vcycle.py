@@ -25,6 +25,10 @@ from repro.core import (
     synthetic_random_graph,
 )
 from repro.core.partition import partition_vertices
+from repro.core.partition_service import (
+    INCREMENTAL_MAX_CELLS_PER_TASK,
+    _incremental_cells_per_task,
+)
 from repro.core.transform import contracted_clone_graph
 
 
@@ -188,11 +192,13 @@ def test_local_repartition_empty_churn_is_noop():
 
 def test_gear_policy_thresholds_and_validation():
     pol = GearPolicy()
-    assert pol.pick(0.0) == "incremental"
-    assert pol.pick(pol.incremental_max_drift) == "incremental"
-    assert pol.pick(pol.incremental_max_drift + 1e-6) == "local"
-    assert pol.pick(pol.local_max_drift) == "local"
-    assert pol.pick(pol.local_max_drift + 1e-6) == "full"
+    budget = INCREMENTAL_MAX_CELLS_PER_TASK
+    assert pol.pick(0.0, 0.0) == "incremental"
+    assert pol.pick(pol.incremental_max_drift, budget) == "incremental"
+    assert pol.pick(pol.incremental_max_drift + 1e-6, 0.0) == "local"
+    assert pol.pick(0.0, budget + 1e-6) == "local"
+    assert pol.pick(pol.local_max_drift, budget + 1e-6) == "local"
+    assert pol.pick(pol.local_max_drift + 1e-6, 0.0) == "full"
     with pytest.raises(ValueError):
         GearPolicy(incremental_max_drift=0.3, local_max_drift=0.1)
     with pytest.raises(ValueError):
@@ -217,6 +223,24 @@ def test_service_gear_selection_by_churn_fraction():
             assert upd.result.quality.balance >= 1.0
         assert svc.stats.incremental_runs >= 1
         assert svc.stats.local_runs >= 1
+
+
+def test_service_sends_incremental_batches_over_the_work_budget_to_local():
+    # One 1% batch: under the scoring-cell budget at k=16, over it at k=256.
+    edges = synthetic_banded_graph(900, band=10, seed=6)
+    with PartitionService() as svc:
+        for k, gear in ((16, "incremental"), (256, "local")):
+            plan = svc.get(edges, k)
+            ins_u, ins_v, delete_ids = _churn(plan.edges, 0.01, seed=9)
+            cells = _incremental_cells_per_task(plan.edges, ins_u, ins_v, delete_ids, k)
+            assert 0 < cells <= k
+            assert (cells > INCREMENTAL_MAX_CELLS_PER_TASK) == (gear == "local")
+            upd = svc.update(
+                plan.fingerprint, k,
+                insert_u=ins_u, insert_v=ins_v, delete_ids=delete_ids,
+            )
+            assert upd.source == gear, (k, upd.source)
+            assert (upd.drift == 0.0) == (gear == "local")  # rebuilds reset it
         assert svc.stats.full_runs >= 1  # the 50% batch (plus the cold build)
 
 

@@ -273,6 +273,24 @@ class TestIncremental:
         assert counts.max() <= cap
         assert stats.balance_ok
 
+    def test_blocked_scoring_matches_unblocked(self, monkeypatch):
+        """Scoring rows in bounded (rows, k) blocks — what keeps the gear
+        inside host memory at k=1024 on millions of tasks — must not change
+        a single label."""
+        from repro.core import partition_service as ps
+
+        e = synthetic_powerlaw_graph(1500, 6000, seed=2)
+        k = 16
+        res = edge_partition(e, k, method="ep")
+        ins_u, ins_v, delete_ids = _churn(e, 0.05, seed=2)
+        run = lambda: incremental_repartition(  # noqa: E731
+            e, res.labels, k, insert_u=ins_u, insert_v=ins_v, delete_ids=delete_ids)
+        _, whole, st_whole = run()
+        monkeypatch.setattr(ps, "_SCORE_BLOCK_CELLS", 3 * k)  # 3-row blocks
+        _, blocked, st_blocked = run()
+        assert st_whole.moves > 0 and st_blocked.moves == st_whole.moves
+        np.testing.assert_array_equal(blocked, whole)
+
     def test_cut_within_tolerance_of_full(self):
         e = synthetic_mesh_graph(40, seed=0)
         k = 16
