@@ -56,6 +56,7 @@ from typing import Optional
 
 import numpy as np
 
+from . import tracing
 from .edge_partition import EdgePartitionResult, edge_partition
 from .graph import EdgeList, affinity_graph_from_coo, csr_from_edges
 from .metrics import evaluate_edge_partition
@@ -125,14 +126,15 @@ def graph_fingerprint(
     lets SpMV requests mix in (n_rows, n_cols) so a bipartite affinity
     graph and a plain graph with identical arrays never collide.
     """
-    h = hashlib.blake2b(digest_size=16)
-    meta = (edges.n, edges.m, k, pad, method, seed) + tuple(extra)
-    if opts is not None:
-        meta = meta + dataclasses.astuple(opts)
-    h.update(repr(meta).encode())
-    h.update(np.ascontiguousarray(edges.u, dtype=np.int64).tobytes())
-    h.update(np.ascontiguousarray(edges.v, dtype=np.int64).tobytes())
-    return h.hexdigest()
+    with tracing.span("plan.fingerprint"):
+        h = hashlib.blake2b(digest_size=16)
+        meta = (edges.n, edges.m, k, pad, method, seed) + tuple(extra)
+        if opts is not None:
+            meta = meta + dataclasses.astuple(opts)
+        h.update(repr(meta).encode())
+        h.update(np.ascontiguousarray(edges.u, dtype=np.int64).tobytes())
+        h.update(np.ascontiguousarray(edges.v, dtype=np.int64).tobytes())
+        return h.hexdigest()
 
 
 def _multilevel_stage_times(stats) -> dict:
@@ -1255,13 +1257,7 @@ class ServiceStats:
     full_runs: int = 0
     incremental_runs: int = 0
     local_runs: int = 0
-    # Updates whose chosen gear escalated on its own quality signal
-    # (incremental -> local on cut growth / balance, local -> full on
-    # unrecoverable balance).
-    incremental_fallbacks: int = 0
     evictions: int = 0
-    lookup_time_s: float = 0.0
-    compute_time_s: float = 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -1352,7 +1348,6 @@ def _update_plan_job(req: _UpdateRequest) -> tuple[ServicePlan, dict]:
         gear = "full"
     new_edges, labels, inc = None, None, None
     result = None
-    escalated = False
     gear_times: dict = {}
     stage_times: dict = {}
     vcycle = None
@@ -1391,7 +1386,6 @@ def _update_plan_job(req: _UpdateRequest) -> tuple[ServicePlan, dict]:
             )
         else:
             gear = "local" if req.method == "ep" else "full"
-            escalated = True
 
     if result is None and gear == "local":
         tg = time.perf_counter()
@@ -1426,7 +1420,6 @@ def _update_plan_job(req: _UpdateRequest) -> tuple[ServicePlan, dict]:
             )
         else:
             gear = "full"
-            escalated = True
 
     if result is None:
         gear = "full"
@@ -1498,7 +1491,6 @@ def _update_plan_job(req: _UpdateRequest) -> tuple[ServicePlan, dict]:
     return sp, {
         "kind": "update",
         "source": source,
-        "fallback": escalated,
         "churn_key": req.churn_key,
     }
 
@@ -1651,12 +1643,10 @@ class PartitionService:
 
     def lookup(self, fingerprint: str, tenant: str = "default") -> Optional[ServicePlan]:
         """Warm-path cache probe: O(1) dict hit, no partitioning."""
-        t0 = time.perf_counter()
         plan = self._cache.get(fingerprint, tenant)
-        with self._lock:
-            if plan is not None:
+        if plan is not None:
+            with self._lock:
                 self.stats.hits += 1
-            self.stats.lookup_time_s += time.perf_counter() - t0
         return plan
 
     def __len__(self) -> int:
@@ -1711,7 +1701,6 @@ class PartitionService:
         plan, _ = value
         with self._lock:
             self.stats.full_runs += 1
-            self.stats.compute_time_s += plan.compute_time_s
             self.stats.evictions += self._cache.put(plan, tenant=ticket.tenant)
         return plan
 
@@ -1724,9 +1713,6 @@ class PartitionService:
                 self.stats.local_runs += 1
             else:
                 self.stats.full_runs += 1
-            if info["fallback"]:
-                self.stats.incremental_fallbacks += 1
-            self.stats.compute_time_s += plan.compute_time_s
             self._churn_memo[info["churn_key"]] = plan.fingerprint
             while len(self._churn_memo) > 4 * self.max_entries:
                 self._churn_memo.popitem(last=False)

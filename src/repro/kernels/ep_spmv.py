@@ -135,6 +135,7 @@ def _launch(kernel, task_ops, x_tile, y_max: int) -> jax.Array:
         out_specs=pl.BlockSpec((SUBLANES, yp), lambda p: (p, 0)),
         out_shape=jax.ShapeDtypeStruct((kp, yp), task_ops[0].dtype),
         interpret=_backend.pallas_interpret(),
+        name="ep_spmv",
     )(*ops)
     return out[:k, :y_max]
 

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import tracing
 from ..core.reorder import PackPlan
 from . import ep_spmv as _spmv
 from . import moe_mlp as _moe
@@ -189,13 +190,10 @@ def make_bucketed_spmv_fn(spec: BucketSpec):
     short micro-batches and de-pad each row to its request's true
     ``n_rows`` on the way out.
     """
-    return jax.jit(functools.partial(_spmv_batch, spec))
+    def ep_spmv_batch(vals, x_lidx, y_lidx, x_gidx, y_gidx, x):
+        return _spmv_batch(spec, vals, x_lidx, y_lidx, x_gidx, y_gidx, x)
 
-
-def _spmv_plan(spec: BucketSpec, vals, x_lidx, y_lidx, x_gidx, y_gidx, x):
-    """One plan's ``(k, ·)`` operands and ``x (n_cols,)`` -> ``y (n_rows,)``."""
-    ops = (a[None] for a in (vals, x_lidx, y_lidx, x_gidx, y_gidx, x))
-    return _spmv_batch(spec, *ops)[0]
+    return jax.jit(ep_spmv_batch)
 
 
 # ---------------------------------------------------------------------------
@@ -272,8 +270,16 @@ def make_ep_spmv_fn(
     spec = BucketSpec(k=plan.k, n_rows=plan.n_rows, n_cols=plan.n_cols,
                       e_max=plan.e_max, x_max=plan.x_max, y_max=plan.y_max,
                       batch=1, mode=mode)
-    operands = [jnp.asarray(a) for a in pad_plan_operands(plan, np.asarray(vals), spec)]
-    return functools.partial(jax.jit(functools.partial(_spmv_plan, spec)), *operands)
+    host = pad_plan_operands(plan, np.asarray(vals), spec)
+    tracing.count("serve.h2d_bytes", sum(a.nbytes for a in host))
+    operands = [jnp.asarray(a) for a in host]
+
+    def ep_spmv_plan(vals, x_lidx, y_lidx, x_gidx, y_gidx, x):
+        """One plan's ``(k, ·)`` operands and ``x (n_cols,)`` -> ``y (n_rows,)``."""
+        ops = (a[None] for a in (vals, x_lidx, y_lidx, x_gidx, y_gidx, x))
+        return _spmv_batch(spec, *ops)[0]
+
+    return functools.partial(jax.jit(ep_spmv_plan), *operands)
 
 
 def ep_spmv(

@@ -41,6 +41,7 @@ from typing import Any, Callable, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import tracing
 from ..core.partition_service import (
     AdmissionRejectedError,
     PartitionService,
@@ -535,9 +536,16 @@ class GraphServer:
     # -- plan + bucket resolution ------------------------------------------
 
     def _plan_for(self, req: GraphRequest) -> tuple[ServicePlan, bool, bool, bool]:
+        # The span closes after the resolution's frame is gone: freeing the
+        # request's affinity graph is part of what resolution costs.
+        with tracing.span("plan.resolve"):
+            return self._resolve_plan(req)
+
+    def _resolve_plan(self, req: GraphRequest) -> tuple[ServicePlan, bool, bool, bool]:
         from ..core.graph import affinity_graph_from_coo
 
-        edges = affinity_graph_from_coo(req.n_rows, req.n_cols, req.rows, req.cols)
+        with tracing.span("plan.affinity"):
+            edges = affinity_graph_from_coo(req.n_rows, req.n_cols, req.rows, req.cols)
         tenant = req.tenant if req.tenant is not None else self.tenant
         priority = req.priority if req.priority is not None else self.priority
         level = self.brownout_level()
@@ -645,28 +653,53 @@ class GraphServer:
 
     # -- batched execution --------------------------------------------------
 
-    def _run_bucket_batch(self, label: str, spec: BucketSpec,
-                          group: list[_Pending]) -> None:
-        """Execute up to ``spec.batch`` same-bucket requests as one launch."""
+    def _pending(self, request: GraphRequest, sp: ServicePlan, ticket_hit: bool,
+                 stale: bool, degraded: bool) -> tuple[_Pending, Optional[str]]:
+        """The request's handle and its bucket label (None: dedicated)."""
+        bucket = self._bucket_for(sp)
+        label, ops = None, None
+        if bucket is not None:
+            label, spec = bucket
+            ops = self._bucket_operands(request, sp, label, spec)
+        p = _Pending(request, sp, ticket_hit, ops, time.perf_counter(),
+                     stale=stale, degraded=degraded)
+        return p, label
+
+    def _launch(self, label: Optional[str], group: list[_Pending]) -> tuple[Any, bool]:
+        """Stage ``group``'s operands, copy them to the device and dispatch
+        one launch without waiting for it: a dedicated request alone, or up
+        to ``spec.batch`` same-bucket requests stacked.  Returns the
+        device's y and whether the kernel came from the compile cache."""
         misses_before = self.compile_cache.misses
-        fn = self._bucket_fn(label, spec)
+        if label is None:
+            (p,) = group
+            fn = self._dedicated_fn(p.request, p.sp)
+            staged = (p.request.x,)
+        else:
+            spec = self._specs[label]
+            fn = self._bucket_fn(label, spec)
+            b = spec.batch
+            vp = np.zeros((b, spec.k, spec.e_max), dtype=np.float32)
+            xl = np.zeros((b, spec.k, spec.e_max), dtype=np.int32)
+            yl = np.zeros((b, spec.k, spec.e_max), dtype=np.int32)
+            xg = np.zeros((b, spec.k, spec.x_max), dtype=np.int32)
+            # Empty batch slots scatter to the sentinel row, like plan tails.
+            yg = np.full((b, spec.k, spec.y_max), spec.n_rows, dtype=np.int32)
+            xs = np.zeros((b, spec.n_cols), dtype=np.float32)
+            for i, p in enumerate(group):
+                vp[i], xl[i], yl[i], xg[i], yg[i] = p.operands
+                xs[i, : p.request.n_cols] = p.request.x
+            staged = (vp, xl, yl, xg, yg, xs)
         kernel_hit = self.compile_cache.misses == misses_before
-        b = spec.batch
-        vp = np.zeros((b, spec.k, spec.e_max), dtype=np.float32)
-        xl = np.zeros((b, spec.k, spec.e_max), dtype=np.int32)
-        yl = np.zeros((b, spec.k, spec.e_max), dtype=np.int32)
-        xg = np.zeros((b, spec.k, spec.x_max), dtype=np.int32)
-        # Empty batch slots scatter to the sentinel row, like plan tails.
-        yg = np.full((b, spec.k, spec.y_max), spec.n_rows, dtype=np.int32)
-        xs = np.zeros((b, spec.n_cols), dtype=np.float32)
-        for i, p in enumerate(group):
-            vp[i], xl[i], yl[i], xg[i], yg[i] = p.operands
-            xs[i, : p.request.n_cols] = p.request.x
-        ys = np.asarray(
-            fn(jnp.asarray(vp), jnp.asarray(xl), jnp.asarray(yl),
-               jnp.asarray(xg), jnp.asarray(yg), jnp.asarray(xs))
-        )
+        tracing.count("serve.h2d_bytes", sum(a.nbytes for a in staged))
+        return fn(*(jnp.asarray(a) for a in staged)), kernel_hit
+
+    def _finish(self, label: Optional[str], group: list[_Pending], y, kernel_hit: bool) -> None:
+        """Resolve each handle of one launch; a bucket's stacked y is
+        de-padded to each request's own rows."""
         self._record_batch(len(group))
+        if label is not None:
+            ys = np.asarray(y)
         for i, p in enumerate(group):
             info = ServeInfo(
                 fingerprint=p.sp.fingerprint,
@@ -681,29 +714,14 @@ class GraphServer:
                 stale=p.stale,
                 degraded=p.degraded,
             )
-            p.result = ServeResult(y=jnp.asarray(ys[i, : p.request.n_rows]), info=info)
+            out = y if label is None else jnp.asarray(ys[i, : p.request.n_rows])
+            p.result = ServeResult(y=out, info=info)
             p.event.set()
 
-    def _run_dedicated(self, p: _Pending) -> None:
-        misses_before = self.compile_cache.misses
-        fn = self._dedicated_fn(p.request, p.sp)
-        kernel_hit = self.compile_cache.misses == misses_before
-        y = fn(jnp.asarray(p.request.x))
-        self._record_batch(1)
-        info = ServeInfo(
-            fingerprint=p.sp.fingerprint,
-            cache_hit=p.ticket_hit,
-            source=p.sp.source,
-            tenant=p.request.tenant if p.request.tenant is not None else self.tenant,
-            partition_time_s=p.sp.compute_time_s,
-            bucket=None,
-            kernel_cache_hit=kernel_hit,
-            batch_size=1,
-            stale=p.stale,
-            degraded=p.degraded,
-        )
-        p.result = ServeResult(y=y, info=info)
-        p.event.set()
+    def _run(self, label: Optional[str], group: list[_Pending]) -> None:
+        with tracing.span("serve.launch"):
+            y, kernel_hit = self._launch(label, group)
+        self._finish(label, group, y, kernel_hit)
 
     def _batch_loop(self) -> None:
         wait_s = self.max_wait_ms / 1000.0
@@ -736,12 +754,14 @@ class GraphServer:
                         timeout=None if deadline is None else max(deadline - now, 0.0)
                     )
             for label, group in todo:
+                for p in group:
+                    tracing.interval("serve.queue_wait", p.t_enqueue, now)
                 try:
                     if label is None:
                         for p in group:
-                            self._run_dedicated(p)
+                            self._run(None, [p])
                     else:
-                        self._run_bucket_batch(label, self._specs[label], group)
+                        self._run(label, group)
                 except BaseException as e:  # resolve waiters, keep serving
                     for p in group:
                         if not p.event.is_set():
@@ -753,17 +773,10 @@ class GraphServer:
     def serve(self, request: GraphRequest) -> ServeResult:
         """Synchronous lane: resolve plan, run a batch-of-1 immediately."""
         sp, ticket_hit, stale, degraded = self._plan_for(request)
-        bucket = self._bucket_for(sp)
-        if bucket is None:
-            p = _Pending(request, sp, ticket_hit, None, time.perf_counter(),
-                         stale=stale, degraded=degraded)
-            self._run_dedicated(p)
-            return p.wait()
-        label, spec = bucket
-        ops = self._bucket_operands(request, sp, label, spec)
-        p = _Pending(request, sp, ticket_hit, ops, time.perf_counter(),
-                     stale=stale, degraded=degraded)
-        self._run_bucket_batch(label, spec, [p])
+        with tracing.span("serve.launch"):
+            p, label = self._pending(request, sp, ticket_hit, stale, degraded)
+            y, kernel_hit = self._launch(label, [p])
+        self._finish(label, [p], y, kernel_hit)
         return p.wait()
 
     def submit(self, request: GraphRequest) -> _Pending:
@@ -776,16 +789,8 @@ class GraphServer:
         if self._batcher is None:
             raise RuntimeError("this GraphServer was built with start_batcher=False")
         sp, ticket_hit, stale, degraded = self._plan_for(request)
-        bucket = self._bucket_for(sp)
-        if bucket is None:
-            p = _Pending(request, sp, ticket_hit, None, time.perf_counter(),
-                         stale=stale, degraded=degraded)
-            label = None
-        else:
-            label, spec = bucket
-            ops = self._bucket_operands(request, sp, label, spec)
-            p = _Pending(request, sp, ticket_hit, ops, time.perf_counter(),
-                         stale=stale, degraded=degraded)
+        with tracing.span("serve.launch"):
+            p, label = self._pending(request, sp, ticket_hit, stale, degraded)
         with self._cv:
             if self._closed:
                 raise RuntimeError("GraphServer is closed")
@@ -794,7 +799,9 @@ class GraphServer:
         return p
 
     def stats(self) -> dict:
-        """Compile-cache counters + batch-size histogram + per-bucket specs."""
+        """Compile-cache counters + batch-size histogram + per-bucket specs,
+        and under ``"spans"`` the process's span and counter totals
+        (:func:`repro.core.tracing.snapshot`)."""
         with self._lock:
             hist = dict(sorted(self._batch_hist.items()))
             per_bucket = {
@@ -816,6 +823,7 @@ class GraphServer:
             s["degraded_serves"] = self._degraded_serves
             s["brownout_rejects"] = self._brownout_rejects
         s["brownout_level"] = self.brownout_level()
+        s["spans"] = tracing.snapshot()
         return s
 
     def metrics(self):
