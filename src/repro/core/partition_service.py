@@ -14,7 +14,7 @@ modules and are independently testable:
     (queue depth, worker utilization, latency histograms).  Results are
     published with an atomic front/back `DoubleBuffer` swap so readers
     never observe a half-built plan — §4.2's async optimization thread.
-  * **Caching** (`plan_cache.PlanCache` keyed by `graph_fingerprint`) —
+  * **Caching** (`plan_cache.PlanCache` keyed by `plan_key`) —
     §4.2 amortizes one partitioning over many kernel launches on the same
     graph; in a serving system the same graph arrives from many requests
     *and tenants*, so plans are memoized under a cheap content hash with
@@ -101,6 +101,8 @@ __all__ = [
     "incremental_repartition",
     "incremental_repartition_reference",
     "local_repartition",
+    "plan_key",
+    "spmv_plan_key",
 ]
 
 
@@ -116,25 +118,77 @@ def graph_fingerprint(
     opts: MultilevelOptions | None = None,
     method: str = "ep",
     seed: int = 0,
-    extra: tuple = (),
 ) -> str:
-    """Cheap content hash identifying a partition request.
+    """Cheap content hash identifying a partition request on a plain graph.
 
     Hashes (n, m, k, pad, method, seed, option fields, endpoint arrays) —
     O(m) bytes through blake2b, microseconds to milliseconds even for
-    million-edge graphs, versus seconds for a multilevel run.  ``extra``
-    lets SpMV requests mix in (n_rows, n_cols) so a bipartite affinity
-    graph and a plain graph with identical arrays never collide.
+    million-edge graphs, versus seconds for a multilevel run.  SpMV
+    requests are keyed by :func:`spmv_plan_key` instead.
     """
     with tracing.span("plan.fingerprint"):
         h = hashlib.blake2b(digest_size=16)
-        meta = (edges.n, edges.m, k, pad, method, seed) + tuple(extra)
+        meta = (edges.n, edges.m, k, pad, method, seed)
         if opts is not None:
             meta = meta + dataclasses.astuple(opts)
         h.update(repr(meta).encode())
         h.update(np.ascontiguousarray(edges.u, dtype=np.int64).tobytes())
         h.update(np.ascontiguousarray(edges.v, dtype=np.int64).tobytes())
         return h.hexdigest()
+
+
+def spmv_plan_key(
+    n_rows: int,
+    n_cols: int,
+    rows: np.ndarray,
+    cols: np.ndarray,
+    k: int,
+    pad: int = 0,
+    opts: MultilevelOptions | None = None,
+    method: str = "ep",
+    seed: int = 0,
+) -> str:
+    """Content key of an SpMV plan request, from the request's own COO.
+
+    The affinity graph is a bijection of ``(rows, cols)`` given ``n_cols``,
+    so the COO identifies the plan as well as the graph would, and a warm
+    hit needs no graph at all.  Contiguous int64 ``rows``/``cols`` are
+    hashed in place through memoryviews (anything else is converted
+    first), after the shapes and options; sha256, a full 256-bit content
+    digest, so a caller that edits its arrays in place gets a new key.
+    """
+    with tracing.span("plan.fingerprint"):
+        rows = np.ascontiguousarray(rows, dtype=np.int64)
+        cols = np.ascontiguousarray(cols, dtype=np.int64)
+        if rows.shape != cols.shape:
+            raise ValueError("rows and cols must have identical shapes")
+        meta = ("spmv", n_rows, n_cols, rows.size, k, pad, method, seed)
+        if opts is not None:
+            meta = meta + dataclasses.astuple(opts)
+        h = hashlib.sha256(repr(meta).encode())
+        h.update(memoryview(rows).cast("B"))
+        h.update(memoryview(cols).cast("B"))
+        return h.hexdigest()
+
+
+def plan_key(
+    edges: Optional[EdgeList],
+    k: int,
+    pad: int,
+    opts: MultilevelOptions | None,
+    method: str,
+    seed: int,
+    coo: Optional[tuple],
+) -> str:
+    """The plan cache's key for one request: :func:`spmv_plan_key` of the
+    COO when there is one (every SpMV path), else :func:`graph_fingerprint`
+    of the edges."""
+    if coo is not None:
+        n_rows, n_cols, rows, cols = coo
+        return spmv_plan_key(n_rows, n_cols, rows, cols, k, pad, opts, method, seed)
+    if edges is None:
+        raise ValueError("a request without coo= needs its edges")
+    return graph_fingerprint(edges, k, pad, opts, method, seed)
 
 
 def _multilevel_stage_times(stats) -> dict:
@@ -1272,7 +1326,7 @@ class ServiceStats:
 @dataclasses.dataclass
 class _FullRequest:
     fingerprint: str
-    edges: EdgeList
+    edges: Optional[EdgeList]  # None: built from ``coo`` by the job
     k: int
     method: str
     opts: MultilevelOptions | None
@@ -1283,7 +1337,11 @@ class _FullRequest:
 
 def _full_plan_job(req: _FullRequest) -> tuple[ServicePlan, dict]:
     t0 = time.perf_counter()
-    result = edge_partition(req.edges, req.k, method=req.method, opts=req.opts, seed=req.seed)
+    edges = req.edges
+    if edges is None:
+        with tracing.span("plan.affinity"):
+            edges = affinity_graph_from_coo(*req.coo)
+    result = edge_partition(edges, req.k, method=req.method, opts=req.opts, seed=req.seed)
     t_part = time.perf_counter() - t0
     plan = None
     padding = None
@@ -1301,7 +1359,7 @@ def _full_plan_job(req: _FullRequest) -> tuple[ServicePlan, dict]:
         fingerprint=req.fingerprint,
         result=result,
         plan=plan,
-        edges=req.edges,
+        edges=edges,
         source="full",
         compute_time_s=dt,
         coo=req.coo,
@@ -1465,11 +1523,11 @@ def _update_plan_job(req: _UpdateRequest) -> tuple[ServicePlan, dict]:
         plan = build_pack_plan(n_rows, n_cols, rows, cols, labels, req.k, pad=req.pad)
         padding = PlanPadding.from_plan(plan, req.pad)
     stage_times["pack"] = time.perf_counter() - t_pack0
-    # Content fingerprint of the post-churn graph — hashed here on the
-    # worker so the request path stays O(churn), not O(m).
-    extra = (base.coo[0], base.coo[1]) if base.coo is not None else ()
-    fingerprint = graph_fingerprint(
-        new_edges, req.k, req.pad, req.opts, req.method, req.seed, extra
+    # Content key of the post-churn graph (of its COO for an SpMV plan, so
+    # a request carrying that COO hits it) — hashed here on the worker so
+    # the request path stays O(churn), not O(m).
+    fingerprint = plan_key(
+        new_edges, req.k, req.pad, req.opts, req.method, req.seed, coo
     )
     dt = time.perf_counter() - t0
     sp = ServicePlan(
@@ -1723,7 +1781,7 @@ class PartitionService:
 
     def submit(
         self,
-        edges: EdgeList,
+        edges: Optional[EdgeList],
         k: int,
         method: str = "ep",
         opts: MultilevelOptions | None = None,
@@ -1747,10 +1805,14 @@ class PartitionService:
         shed (:class:`DeadlineShedError`) instead of occupying a worker.
         With a bounded scheduler (``max_queue_depth``), an over-share
         submit raises :class:`AdmissionRejectedError` carrying a
-        ``retry_after_s`` hint."""
+        ``retry_after_s`` hint.
+
+        An SpMV request passes ``coo=(n_rows, n_cols, rows, cols)`` and is
+        keyed by :func:`spmv_plan_key` of it; its ``edges`` may then be
+        ``None``, and the affinity graph is built by the job that computes
+        the plan — a warm hit never builds it."""
         opts = opts if opts is not None else self.default_opts
-        extra = (coo[0], coo[1]) if coo is not None else ()
-        fingerprint = graph_fingerprint(edges, k, pad, opts, method, seed, extra)
+        fingerprint = plan_key(edges, k, pad, opts, method, seed, coo)
         deadline = time.perf_counter() + timeout if timeout is not None else None
         with self._lock:
             # Hit/miss decided under the lock: a dispatcher finishing the
@@ -1785,7 +1847,7 @@ class PartitionService:
 
     def get(
         self,
-        edges: EdgeList,
+        edges: Optional[EdgeList],
         k: int,
         method: str = "ep",
         opts: MultilevelOptions | None = None,
@@ -1818,12 +1880,12 @@ class PartitionService:
         tenant: str = "default",
         priority: int = 0,
     ) -> ServicePlan:
-        """SpMV request path: affinity graph from COO + a PackPlan (§4.1)."""
+        """SpMV request path: a PackPlan (§4.1) keyed by the COO; the
+        affinity graph is built only when the plan has to be computed."""
         rows = np.asarray(rows, dtype=np.int64)
         cols = np.asarray(cols, dtype=np.int64)
-        edges = affinity_graph_from_coo(n_rows, n_cols, rows, cols)
         return self.get(
-            edges,
+            None,
             k,
             method=method,
             opts=opts,

@@ -51,7 +51,7 @@ from typing import Any, Callable, Optional, Sequence
 
 import numpy as np
 
-from .graph import EdgeList, affinity_graph_from_coo
+from .graph import EdgeList
 from .health import CircuitBreaker, HeartbeatRegistry, OverloadSchedule
 from .partition import MultilevelOptions
 from .partition_service import (
@@ -59,7 +59,7 @@ from .partition_service import (
     PartitionService,
     ServicePlan,
     ServiceStats,
-    graph_fingerprint,
+    plan_key,
 )
 from .plan_cache import PlanCache
 from .plan_scheduler import (
@@ -1073,7 +1073,7 @@ class ReplicaGroup:
 
     def submit(
         self,
-        edges: EdgeList,
+        edges: Optional[EdgeList],
         k: int,
         method: str = "ep",
         opts: MultilevelOptions | None = None,
@@ -1092,8 +1092,7 @@ class ReplicaGroup:
         even with budget left — and fails the ticket with
         :class:`ReplicaExhaustedError` noting the deadline."""
         opts = opts if opts is not None else self._replicas[0].svc.default_opts
-        extra = (coo[0], coo[1]) if coo is not None else ()
-        fp = graph_fingerprint(edges, k, pad, opts, method, seed, extra)
+        fp = plan_key(edges, k, pad, opts, method, seed, coo)
 
         def submit_fn(svc: PartitionService) -> PlanTicket:
             return svc.submit(edges, k, method=method, opts=opts, seed=seed,
@@ -1119,7 +1118,7 @@ class ReplicaGroup:
                                     match_fn, tenant, priority, buffer,
                                     timeout=timeout)
 
-    def get(self, edges: EdgeList, k: int, method: str = "ep",
+    def get(self, edges: Optional[EdgeList], k: int, method: str = "ep",
             opts: MultilevelOptions | None = None, seed: int = 0,
             pad: int = 128, coo: Optional[tuple] = None,
             timeout: float | None = None, tenant: str = "default",
@@ -1135,8 +1134,7 @@ class ReplicaGroup:
                       tenant: str = "default", priority: int = 0) -> ServicePlan:
         rows = np.asarray(rows, dtype=np.int64)
         cols = np.asarray(cols, dtype=np.int64)
-        edges = affinity_graph_from_coo(n_rows, n_cols, rows, cols)
-        return self.get(edges, k, method=method, opts=opts, seed=seed, pad=pad,
+        return self.get(None, k, method=method, opts=opts, seed=seed, pad=pad,
                         coo=(n_rows, n_cols, rows, cols), timeout=timeout,
                         tenant=tenant, priority=priority)
 

@@ -47,7 +47,7 @@ from ..core.partition_service import (
     PartitionService,
     PlanTicket,
     ServicePlan,
-    graph_fingerprint,
+    spmv_plan_key,
 )
 from ..core.reorder import PackPlan
 from ..kernels.ops import (
@@ -525,27 +525,26 @@ class GraphServer:
                 svc.hedge = self._hedge_saved
                 self._hedge_saved = None
 
-    def _fingerprint(self, req: GraphRequest, edges) -> str:
-        """The fingerprint ``service.submit`` would assign this request —
+    def _fingerprint(self, req: GraphRequest) -> str:
+        """The key ``service.submit`` would assign this request —
         computed here so the brownout path can probe caches without
         submitting any work."""
         opts = getattr(self.service, "default_opts", None)
-        return graph_fingerprint(edges, self.k, self.pad, opts, "ep", 0,
-                                 (req.n_rows, req.n_cols))
+        return spmv_plan_key(req.n_rows, req.n_cols, req.rows, req.cols,
+                             self.k, self.pad, opts)
 
     # -- plan + bucket resolution ------------------------------------------
 
     def _plan_for(self, req: GraphRequest) -> tuple[ServicePlan, bool, bool, bool]:
-        # The span closes after the resolution's frame is gone: freeing the
-        # request's affinity graph is part of what resolution costs.
         with tracing.span("plan.resolve"):
-            return self._resolve_plan(req)
+            resolved = self._resolve_plan(req)
+            if resolved[1]:
+                # Answered from a cache, keyed by the request's COO: no
+                # affinity graph was built.
+                tracing.count("plan.warm_hits", 1)
+            return resolved
 
     def _resolve_plan(self, req: GraphRequest) -> tuple[ServicePlan, bool, bool, bool]:
-        from ..core.graph import affinity_graph_from_coo
-
-        with tracing.span("plan.affinity"):
-            edges = affinity_graph_from_coo(req.n_rows, req.n_cols, req.rows, req.cols)
         tenant = req.tenant if req.tenant is not None else self.tenant
         priority = req.priority if req.priority is not None else self.priority
         level = self.brownout_level()
@@ -558,7 +557,7 @@ class GraphServer:
             # without admitting new work.  A cache miss rejects outright —
             # but is NOT counted as fresh rejection pressure, so brownout
             # cannot sustain itself once the real overload has passed.
-            fp = self._fingerprint(req, edges)
+            fp = self._fingerprint(req)
             cached = lookup(fp, tenant)
             if cached is not None:
                 with self._lock:
@@ -572,8 +571,10 @@ class GraphServer:
                 retry_after_s=self.brownout_window_s, tenant=tenant,
                 reason="brownout")
         try:
+            # The service keys the plan by the COO and builds the affinity
+            # graph only if it has to compute the plan.
             ticket = self.service.submit(
-                edges,
+                None,
                 self.k,
                 pad=self.pad,
                 coo=(req.n_rows, req.n_cols, req.rows, req.cols),
@@ -591,7 +592,7 @@ class GraphServer:
             self._note_rejection()
             self._apply_brownout(self.brownout_level())
             if lookup is not None:
-                cached = lookup(fp or self._fingerprint(req, edges), tenant)
+                cached = lookup(fp or self._fingerprint(req), tenant)
                 if cached is not None:
                     with self._lock:
                         self._degraded_serves += 1

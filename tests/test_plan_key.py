@@ -1,0 +1,179 @@
+"""The SpMV plan key (``spmv_plan_key``): one content key over the request's
+own COO, the same on every SpMV path, and a warm serve that never builds
+the affinity graph."""
+import dataclasses
+
+import numpy as np
+import pytest
+
+import repro.core.partition_service as ps
+from repro.core import (
+    AdmissionRejectedError,
+    PartitionService,
+    ReplicaGroup,
+    spmv_plan_key,
+    synthetic_bipartite_graph,
+)
+from repro.runtime import BucketPolicy, GraphRequest, GraphServer
+
+N, K, PAD = 96, 4, 8
+
+
+def _coo(seed=0, n=N):
+    _, rows, cols = synthetic_bipartite_graph(n, n, 3, seed=seed)
+    return rows.astype(np.int64), cols.astype(np.int64)
+
+
+def _request(rows, cols, n=N, seed=0):
+    rng = np.random.default_rng(seed)
+    return GraphRequest(n, n, rows, cols, rng.standard_normal(rows.size),
+                        rng.standard_normal(n))
+
+
+def _ref(req):
+    y = np.zeros(req.n_rows, np.float64)
+    np.add.at(y, req.rows, req.vals.astype(np.float64) * req.x[req.cols])
+    return y
+
+
+@pytest.fixture()
+def service():
+    with PartitionService() as svc:
+        yield svc
+
+
+@pytest.fixture()
+def affinity_calls(monkeypatch):
+    """Counts every affinity graph the service builds."""
+    calls = []
+    real = ps.affinity_graph_from_coo
+
+    def counted(*args):
+        calls.append(args[:2])
+        return real(*args)
+
+    monkeypatch.setattr(ps, "affinity_graph_from_coo", counted)
+    return calls
+
+
+def _via_server(svc, rows, cols):
+    server = GraphServer(svc, k=K, pad=PAD, start_batcher=False, bucketing=None)
+    req = _request(rows, cols)
+    key = server.serve(req).info.fingerprint
+    assert server._fingerprint(req) == key  # the brownout probe's key
+    return key
+
+
+PATHS = {
+    "server": _via_server,
+    "submit": lambda svc, rows, cols: svc.submit(
+        None, K, pad=PAD, coo=(N, N, rows, cols)).result(60).fingerprint,
+    "get_spmv_plan": lambda svc, rows, cols: svc.get_spmv_plan(
+        N, N, rows, cols, K, pad=PAD, timeout=60).fingerprint,
+}
+
+
+@pytest.mark.parametrize("group", [False, True], ids=["service", "replicas"])
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_every_spmv_path_gives_one_key(path, group):
+    rows, cols = _coo(seed=1)
+    key = spmv_plan_key(N, N, rows, cols, K, PAD)
+    svc = ReplicaGroup(2) if group else PartitionService()
+    with svc:
+        assert PATHS[path](svc, rows, cols) == key
+        # The same COO in new arrays, on another path: a warm hit.
+        ticket = svc.submit(None, K, pad=PAD, coo=(N, N, rows.copy(), cols.copy()))
+        assert ticket.cache_hit and ticket.result(60).fingerprint == key
+
+
+def test_post_churn_coo_is_served_warm(service, affinity_calls):
+    rows, cols = _coo(seed=2)
+    server = GraphServer(service, k=K, pad=PAD, start_batcher=False, bucketing=None)
+    base = server.serve(_request(rows, cols)).info
+    rng = np.random.default_rng(7)
+    delete_ids = np.sort(rng.choice(rows.size, 6, replace=False))
+    ins_rows, ins_cols = rng.integers(0, N, 6), rng.integers(0, N, 6)
+    new_plan = service.update(base.fingerprint, K, insert_u=ins_cols,
+                              insert_v=N + ins_rows, delete_ids=delete_ids,
+                              pad=PAD, timeout=60)
+    # The churned matrix from the batch alone: kept entries, then inserts.
+    new_rows = np.concatenate([np.delete(rows, delete_ids), ins_rows])
+    new_cols = np.concatenate([np.delete(cols, delete_ids), ins_cols])
+    assert new_plan.fingerprint == spmv_plan_key(N, N, new_rows, new_cols, K, PAD)
+    built = len(affinity_calls)
+    req = _request(new_rows, new_cols, seed=3)
+    res = server.serve(req)
+    assert res.info.cache_hit and res.info.fingerprint == new_plan.fingerprint
+    assert len(affinity_calls) == built
+    np.testing.assert_allclose(np.asarray(res.y), _ref(req), rtol=1e-4, atol=1e-4)
+
+
+def _swap_entries(rows, cols):
+    rows, cols = rows.copy(), cols.copy()
+    i, j = 0, rows.size - 1
+    rows[[i, j]], cols[[i, j]] = rows[[j, i]], cols[[j, i]]
+    return rows, cols
+
+
+CHANGES = {
+    "k": lambda r, c: spmv_plan_key(N, N, r, c, K * 2, PAD),
+    "pad": lambda r, c: spmv_plan_key(N, N, r, c, K, PAD * 2),
+    "n_rows": lambda r, c: spmv_plan_key(N + 1, N, r, c, K, PAD),
+    "n_cols": lambda r, c: spmv_plan_key(N, N + 1, r, c, K, PAD),
+    "swapped_entry": lambda r, c: spmv_plan_key(N, N, *_swap_entries(r, c), K, PAD),
+    "rows_cols_exchanged": lambda r, c: spmv_plan_key(N, N, c, r, K, PAD),
+}
+
+
+@pytest.mark.parametrize("change", sorted(CHANGES))
+def test_key_changes_with_the_request(change):
+    rows, cols = _coo(seed=4)
+    key = spmv_plan_key(N, N, rows, cols, K, PAD)
+    assert len(key) * 4 >= 128
+    assert spmv_plan_key(N, N, rows.astype(np.int32), cols.copy(), K, PAD) == key
+    assert CHANGES[change](rows, cols) != key
+
+
+def test_in_place_edit_after_a_serve_is_a_miss(service):
+    rows, cols = _coo(seed=5)
+    server = GraphServer(service, k=K, pad=PAD, start_batcher=False, bucketing=None)
+    req = _request(rows, cols, seed=5)
+    first = server.serve(req).info
+    req.rows[0] = (req.rows[0] + 1) % N  # the caller's own array, edited
+    res = server.serve(req)
+    assert not res.info.cache_hit and res.info.fingerprint != first.fingerprint
+    np.testing.assert_allclose(np.asarray(res.y), _ref(req), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("lane", ["dedicated", "bucket"])
+def test_warm_serve_builds_no_affinity_graph(service, affinity_calls, lane):
+    server = GraphServer(service, k=K, pad=PAD, start_batcher=False,
+                         bucketing=None if lane == "dedicated" else BucketPolicy())
+    req = _request(*_coo(seed=6), seed=6)
+    cold = server.serve(req)
+    assert not cold.info.cache_hit and len(affinity_calls) == 1
+    for _ in range(3):
+        hits = service.stats.hits
+        res = server.serve(req)
+        assert service.stats.hits == hits + 1
+        assert res.info == dataclasses.replace(cold.info, cache_hit=True,
+                                               kernel_cache_hit=True)
+    assert len(affinity_calls) == 1
+
+
+def test_stale_only_rung_answers_cached_and_rejects_uncached(service, affinity_calls):
+    server = GraphServer(service, k=K, pad=PAD, start_batcher=False, bucketing=None,
+                         brownout_window_s=5.0, brownout_hedge_off=1,
+                         brownout_stale_only=2, brownout_priority_floor=1)
+    hot = _request(*_coo(seed=8), seed=8)
+    server.serve(hot)
+    for _ in range(2):
+        server._note_rejection()
+    hits = service.stats.hits
+    res = server.serve(hot)
+    assert res.info.degraded and res.info.cache_hit
+    assert service.stats.hits == hits + 1
+    with pytest.raises(AdmissionRejectedError) as ei:
+        server.serve(_request(*_coo(seed=9), seed=9))
+    assert ei.value.reason == "brownout"
+    assert len(affinity_calls) == 1

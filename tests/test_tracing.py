@@ -68,8 +68,8 @@ def _request(n, seed):
 
 def _window(lo):
     hi = time.perf_counter()
-    names = ("plan.resolve", "plan.affinity", "plan.fingerprint", "serve.launch",
-             "serve.h2d_bytes", "serve.queue_wait")
+    names = ("plan.resolve", "plan.affinity", "plan.fingerprint", "plan.warm_hits",
+             "serve.launch", "serve.h2d_bytes", "serve.queue_wait")
     return {n: tracing.records(n, lo, hi) for n in names}
 
 
@@ -84,18 +84,36 @@ def test_one_serve_records_plan_resolution_then_launch(service, lane):
     server = GraphServer(service, k=4, pad=8, start_batcher=False,
                          bucketing=None if lane == "dedicated" else BucketPolicy())
     req = _request(96, seed=3)
-    server.serve(req)  # cold: plan build, compile and the plan's own uploads
+
+    def inside(rec, resolve):
+        """Each name's entries that start (and, for spans, end) in it."""
+        return {n: [r for r in rec[n] if resolve[1] <= r[1]
+                    and (n == "plan.warm_hits" or r[2] <= resolve[2])]
+                for n in ("plan.affinity", "plan.fingerprint", "plan.warm_hits")}
+
+    # Cold: the COO key, then the affinity graph for the plan build.
+    lo = time.perf_counter()
+    server.serve(req)  # plan build, compile and the plan's own uploads
+    rec = _window(lo)
+    (resolve,) = rec["plan.resolve"]
+    cold = inside(rec, resolve)
+    (key,), (affinity,) = cold["plan.fingerprint"], cold["plan.affinity"]
+    assert key[2] <= affinity[1] and cold["plan.warm_hits"] == []
+
+    # Warm: the key alone, answered from the cache with no affinity graph.
     lo = time.perf_counter()
     res = server.serve(req)
     rec = _window(lo)
     assert (res.info.bucket is None) == (lane == "dedicated")
+    assert res.info.cache_hit
 
     (resolve,) = rec["plan.resolve"]
     (launch,) = rec["serve.launch"]
-    inside = {n: [r for r in rec[n] if resolve[1] <= r[1] and r[2] <= resolve[2]]
-              for n in ("plan.affinity", "plan.fingerprint")}
-    (affinity,), fingerprints = inside["plan.affinity"], inside["plan.fingerprint"]
-    assert fingerprints and affinity[2] <= fingerprints[0][1]
+    warm = inside(rec, resolve)
+    assert len(warm["plan.fingerprint"]) == 1
+    assert rec["plan.affinity"] == []
+    (hit,) = warm["plan.warm_hits"]
+    assert hit[2] == 1
     assert resolve[2] <= launch[1]
 
     staged = sum(b for _, _, b in rec["serve.h2d_bytes"])
@@ -107,6 +125,7 @@ def test_one_serve_records_plan_resolution_then_launch(service, lane):
     assert rec["serve.queue_wait"] == []
     spans = server.stats()["spans"]
     assert spans["plan.resolve"]["count"] >= 2 and spans["serve.launch"]["total"] > 0
+    assert spans["plan.warm_hits"]["count"] >= 1
 
 
 def test_submit_records_the_queue_wait(service):
