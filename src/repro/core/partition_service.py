@@ -1205,6 +1205,9 @@ class PlanPadding:
     bucket *without* touching the PackPlan arrays: the logical matrix dims,
     the true nnz, and the 128-aligned per-cluster tile ceilings.  Carried on
     :class:`ServicePlan` so bucket selection is O(1) per request.
+    ``xy_loads`` is ``Σ x_count + Σ y_count``, the distinct x and y entries
+    the clusters load (the paper's ``n_touched + C``), so a launch can count
+    how full its tiles are without touching the plan's arrays.
     """
 
     pad: int
@@ -1215,6 +1218,7 @@ class PlanPadding:
     e_max: int
     x_max: int
     y_max: int
+    xy_loads: int = 0
 
     @classmethod
     def from_plan(cls, plan: PackPlan, pad: int) -> "PlanPadding":
@@ -1227,6 +1231,7 @@ class PlanPadding:
             e_max=plan.e_max,
             x_max=plan.x_max,
             y_max=plan.y_max,
+            xy_loads=plan.modeled_loads(),
         )
 
 

@@ -676,8 +676,9 @@ class GraphServer:
             (p,) = group
             fn = self._dedicated_fn(p.request, p.sp)
             staged = (p.request.x,)
+            tiles = p.sp.plan
         else:
-            spec = self._specs[label]
+            spec = tiles = self._specs[label]
             fn = self._bucket_fn(label, spec)
             b = spec.batch
             vp = np.zeros((b, spec.k, spec.e_max), dtype=np.float32)
@@ -693,6 +694,14 @@ class GraphServer:
             staged = (vp, xl, yl, xg, yg, xs)
         kernel_hit = self.compile_cache.misses == misses_before
         tracing.count("serve.h2d_bytes", sum(a.nbytes for a in staged))
+        # How full the launched requests' tiles are: the slots the launch
+        # walks for them against the tasks and distinct x/y entries their
+        # plans have (empty batch slots count in neither).
+        n = len(group)
+        tracing.count("serve.task_slots", n * tiles.k * tiles.e_max)
+        tracing.count("serve.tasks", sum(p.sp.padding.nnz for p in group))
+        tracing.count("serve.xy_slots", n * tiles.k * (tiles.x_max + tiles.y_max))
+        tracing.count("serve.xy_loads", sum(p.sp.padding.xy_loads for p in group))
         return fn(*(jnp.asarray(a) for a in staged)), kernel_hit
 
     def _finish(self, label: Optional[str], group: list[_Pending], y, kernel_hit: bool) -> None:

@@ -78,3 +78,11 @@ def test_pad_below_lane_width(one_chip):
         batch=2, pad=8)
     assert spec.e_max % 128 and spec.k % 8
     assert "tpu_custom_call" in _compiled_text(spec, one_chip)
+
+
+def test_kronecker_widths(one_chip):
+    # The scale-16 Graph500 PPR matrix at k=1024 through the dedicated lane:
+    # its fullest cluster pads every x and y tile to 1920, past one slab.
+    spec = BucketSpec(k=1024, n_rows=65536, n_cols=65536, e_max=1920,
+                      x_max=1920, y_max=1920, batch=1)
+    assert "tpu_custom_call" in _compiled_text(spec, one_chip)
