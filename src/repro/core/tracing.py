@@ -26,8 +26,8 @@ import numpy as np
 
 __all__ = ["RING_SIZE", "Ring", "count", "interval", "records", "snapshot", "span"]
 
-# A 51 s window at 1,000 requests/s with ~6 entries each fits.
-RING_SIZE = 1 << 18
+# A 51 s window at 1,000 requests/s with ~10 entries each fits.
+RING_SIZE = 1 << 19
 
 
 class Ring:

@@ -2,6 +2,9 @@
 own COO, the same on every SpMV path, and a warm serve that never builds
 the affinity graph."""
 import dataclasses
+import hashlib
+import sys
+import threading
 
 import numpy as np
 import pytest
@@ -177,3 +180,82 @@ def test_stale_only_rung_answers_cached_and_rejects_uncached(service, affinity_c
         server.serve(_request(*_coo(seed=9), seed=9))
     assert ei.value.reason == "brownout"
     assert len(affinity_calls) == 1
+
+
+# -- the key's sha256 tree ----------------------------------------------------
+
+LEAF = 1 << 20  # bytes
+LEAF_ENTRIES = LEAF // 8
+
+
+def _serial_key(n_rows, n_cols, rows, cols, k, pad):
+    """The key built leaf by leaf on this thread, from its definition."""
+    meta = ("spmv", n_rows, n_cols, rows.size, k, pad, "ep", 0, "sha256-tree", LEAF)
+    root = hashlib.sha256(b"repro.spmv_plan_key.root\x00" + repr(meta).encode())
+    for a in (rows, cols):
+        raw = np.ascontiguousarray(a, dtype=np.int64).tobytes()
+        for i in range(0, len(raw), LEAF):
+            root.update(hashlib.sha256(
+                b"repro.spmv_plan_key.leaf\x00" + raw[i:i + LEAF]).digest())
+    return root.hexdigest()
+
+
+def _big_coo(nnz, seed=0, n=1 << 20):
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, n, nnz, dtype=np.int64), rng.integers(0, n, nnz, dtype=np.int64)
+
+
+@pytest.mark.parametrize("nnz", [0, LEAF_ENTRIES, LEAF_ENTRIES + 1, 3 * LEAF_ENTRIES + 5],
+                         ids=["empty", "one_leaf", "one_leaf_plus_one", "four_leaves"])
+def test_key_equals_the_serial_tree(nnz):
+    rows, cols = _big_coo(nnz, seed=nnz)
+    n = 1 << 20
+    assert spmv_plan_key(n, n, rows, cols, K, PAD) == _serial_key(n, n, rows, cols, K, PAD)
+
+
+@pytest.mark.parametrize("leaf", ["first", "middle", "last"])
+@pytest.mark.parametrize("array", ["rows", "cols"])
+def test_an_edit_in_any_leaf_changes_the_key(array, leaf):
+    rows, cols = _big_coo(3 * LEAF_ENTRIES, seed=11)
+    n = 1 << 20
+    key = spmv_plan_key(n, n, rows, cols, K, PAD)
+    edited = {"rows": rows, "cols": cols}[array]
+    i = {"first": 3, "middle": LEAF_ENTRIES + 17, "last": edited.size - 1}[leaf]
+    edited[i] = (edited[i] + 1) % n
+    assert spmv_plan_key(n, n, rows, cols, K, PAD) != key
+
+
+def test_keys_from_many_threads_equal_the_serial_keys():
+    n = 1 << 20
+    coos = [_big_coo(2 * LEAF_ENTRIES + 1000 * t, seed=20 + t) for t in range(8)]
+    want = [_serial_key(n, n, r, c, K, PAD) for r, c in coos]
+    got = [[] for _ in coos]
+
+    def work(t):
+        for _ in range(4):
+            got[t].append(spmv_plan_key(n, n, *coos[t], K, PAD))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(t,)) for t in range(len(coos))]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    assert got == [[w] * 4 for w in want]
+
+
+@pytest.mark.parametrize("nnz", [N, 3 * LEAF_ENTRIES], ids=["inline", "pooled"])
+def test_int32_and_strided_inputs_give_the_int64_key(nnz):
+    rows, cols = _big_coo(nnz, seed=30)
+    n = 1 << 20
+    key = spmv_plan_key(n, n, rows, cols, K, PAD)
+    interleaved = np.stack([rows, cols], axis=1)  # (nnz, 2): columns are strided
+    s_rows, s_cols = interleaved[:, 0], interleaved[:, 1]
+    assert not s_rows.flags.c_contiguous and not s_cols.flags.c_contiguous
+    assert spmv_plan_key(n, n, s_rows, s_cols, K, PAD) == key
+    assert spmv_plan_key(n, n, rows.astype(np.int32), cols.astype(np.int32), K, PAD) == key

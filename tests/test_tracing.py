@@ -128,6 +128,32 @@ def test_one_serve_records_plan_resolution_then_launch(service, lane):
     assert spans["plan.warm_hits"]["count"] >= 1
 
 
+@pytest.mark.parametrize("size", ["inline", "pooled"])
+def test_a_serve_counts_the_plan_keys_bytes_and_pooled_leaves(service, size):
+    # 36,864 rows of degree ~4: more than one 1 MiB leaf of int64 in each
+    # of rows and cols, so at least three leaves in all.
+    req = _request(96 if size == "inline" else 36864, seed=5)
+    leaves = 2 * -(-req.rows.nbytes // (1 << 20))
+    assert (leaves >= 3) == (size == "pooled")
+    server = GraphServer(service, k=4, pad=8, start_batcher=False, bucketing=None)
+    before = server.stats()["spans"]
+    lo = time.perf_counter()
+    server.serve(req)
+    hi = time.perf_counter()
+    after = server.stats()["spans"]
+
+    def added(name):
+        was = before.get(name, {"count": 0, "total": 0.0})
+        now = after.get(name, {"count": 0, "total": 0.0})
+        return now["count"] - was["count"], now["total"] - was["total"]
+
+    key_bytes = req.rows.nbytes + req.cols.nbytes
+    assert added("plan.fingerprint_bytes") == (1, key_bytes)
+    assert [b for _, _, b in tracing.records("plan.fingerprint_bytes", lo, hi)] == [key_bytes]
+    pooled = 1 if size == "pooled" else 0
+    assert added("plan.fingerprint_pooled") == (pooled, pooled)
+
+
 def test_submit_records_the_queue_wait(service):
     req = _request(96, seed=4)
     with GraphServer(service, k=4, pad=8, max_wait_ms=1.0) as server:
