@@ -11,10 +11,11 @@ Two phases in one process, through the entry points a user calls
     then a uniform 1% churn through ``update_async`` and one request
     served from the new plan, checked against the churned matrix built
     from the batch alone.
-(b) small graphs — 32 requests over the ``benchmarks/svc_batched.py``
-    families (k=8, three tenants): each once through the synchronous lane,
-    then all through ``GraphServer.submit`` with the micro-batcher on, so
-    same-bucket requests share launches across several shape buckets.
+(b) small graphs — 32 requests over four shape families of random
+    bipartite matrices (k=8, three tenants): each once through the
+    synchronous lane, then all through ``GraphServer.submit`` with the
+    micro-batcher on, so same-bucket requests share launches across
+    several shape buckets.
 
 Every served y is checked against a float64 numpy COO SpMV of the same
 matrix and x: relative L2 error at most 1e-5.  The lines before the last
@@ -35,8 +36,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks.svc_batched import FAMILIES, N_TENANTS  # noqa: E402
-from benchmarks.svc_batched import K as SMALL_K  # noqa: E402
 from repro.core import (  # noqa: E402
     PartitionService,
     stencil_27pt_coo,
@@ -50,6 +49,12 @@ STENCIL_K = 1024
 STENCIL_REQUESTS = 3
 CHURN = 0.01  # fraction of the stencil's non-zeros deleted, and as many inserted
 SMALL_REQUESTS = 32
+# (n_rows, n_cols, nnz_per_row) of the small-graph families; with the default
+# BucketPolicy they land in four shape buckets: r256c256 e1024 and e2048,
+# r512c512 e2048 and e1024.
+FAMILIES = [(150, 150, 4), (150, 150, 16), (300, 300, 5), (300, 300, 3)]
+N_TENANTS = 3
+SMALL_K = 8
 MAX_BATCH = 8
 MAX_WAIT_MS = 20.0
 MAX_REL_ERR = 1e-5
@@ -85,8 +90,8 @@ def stencil_values(rows: np.ndarray, cols: np.ndarray, seed: int) -> np.ndarray:
 
 def uniform_churn(n: int, nnz: int, rng):
     """Delete ``CHURN`` of the non-zeros, chosen uniformly, and insert as
-    many uniformly random ``(row, col)`` couplings — the churn convention of
-    ``benchmarks/svc_service.py``.  Returns ``(delete_ids, rows, cols)``."""
+    many uniformly random ``(row, col)`` couplings: half the batch deletes,
+    half inserts.  Returns ``(delete_ids, rows, cols)``."""
     count = max(int(CHURN * nnz), 1)
     delete_ids = np.sort(rng.choice(nnz, count, replace=False))
     return delete_ids, rng.integers(0, n, count), rng.integers(0, n, count)

@@ -38,7 +38,7 @@ daemon thread (submit → poll → hedge → failover → resolve), so callers k
 the plain future surface (``ticket.result(timeout)``) and identical
 concurrent requests coalesce onto one driver.  :class:`FaultInjector`
 provides deterministic, seeded crash/stall/heartbeat-drop schedules (with an
-injectable clock) for the tests and ``benchmarks/svc_chaos.py``.
+injectable clock) for the tests.
 """
 from __future__ import annotations
 

@@ -7,9 +7,9 @@ assigned MoE architectures).  Pure-jnp oracles live in ``ref.py``.  The
 backend decides how a kernel runs (``backend.py``): interpreted on the CPU,
 where the tests run, and lowered through Mosaic on a TPU.
 
-The model zoo / dry-run path stays pure JAX: Mosaic custom calls neither
-compile on the CPU backend nor contribute FLOPs to ``cost_analysis()``,
-so kernels are an opt-in fast path, not a lowering dependency.
+The model zoo stays pure JAX: Mosaic custom calls contribute no FLOPs to
+``cost_analysis()``, so kernels are an opt-in fast path, not a lowering
+dependency.
 """
 from .ep_spmv import spmv_software_cache, spmv_streaming, spmv_streaming_batched
 from .flash_attention import flash_attention
@@ -20,7 +20,6 @@ from .ops import (
     make_ep_spmv_fn,
     moe_mlp,
     pad_plan_operands,
-    resolve_plan,
     spmv_hbm_traffic_model,
 )
 
@@ -32,7 +31,6 @@ __all__ = [
     "make_ep_spmv_fn",
     "moe_mlp",
     "pad_plan_operands",
-    "resolve_plan",
     "spmv_hbm_traffic_model",
     "spmv_software_cache",
     "spmv_streaming",

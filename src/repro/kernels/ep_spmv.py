@@ -34,8 +34,8 @@ exact); the products are scattered into the y tile the same way, summed
 over the chunk.  Tiles wider than ``SLAB`` lanes are compared a slab at a
 time, which bounds each ``(8, 128, SLAB)`` temporary to 4 MiB of VMEM.  The
 wrapper pads k to a multiple of 8 with empty clusters (zero values, nothing
-read back) and the tile widths to multiples of 128, so every pack ``pad`` —
-the tile knob swept by benchmarks/table3_block_size.py — gives legal tiles.
+read back) and the tile widths to multiples of 128, so every pack ``pad``
+(the pack plan's tile knob) gives legal tiles.
 The same kernel body runs interpreted on the CPU (``backend.pallas_interpret``).
 """
 from __future__ import annotations

@@ -29,14 +29,12 @@ Two compilation contracts coexist:
 
 This module takes only host-side ``PackPlan``s (+ the padding spec);
 scheduler handles (ServicePlan / PlanTicket) are resolved by the request
-layer (``repro.runtime.request``) — the pass-through acceptance here is a
-deprecated shim.
+layer (``repro.runtime.request``).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 from typing import Literal
 
 import jax
@@ -55,7 +53,6 @@ __all__ = [
     "make_ep_spmv_fn",
     "moe_mlp",
     "pad_plan_operands",
-    "resolve_plan",
     "spmv_hbm_traffic_model",
 ]
 
@@ -197,44 +194,21 @@ def make_bucketed_spmv_fn(spec: BucketSpec):
 
 
 # ---------------------------------------------------------------------------
-# Per-plan compilation (+ deprecated scheduler-handle shims)
+# Per-plan compilation
 # ---------------------------------------------------------------------------
-
-
-def resolve_plan(plan, timeout: float | None = None) -> PackPlan:
-    """Deprecated alias: plan-kind resolution moved to the request layer.
-
-    Use :func:`repro.runtime.request.resolve_plan` — the kernel layer takes
-    only host-side ``PackPlan``s now, and unwrapping scheduler handles
-    (ServicePlan / PlanTicket, with their timeout semantics) is a serving
-    concern, not a kernel one.
-    """
-    warnings.warn(
-        "repro.kernels.resolve_plan is deprecated; use "
-        "repro.runtime.request.resolve_plan",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    from ..runtime.request import resolve_plan as _resolve  # lazy: layering
-
-    return _resolve(plan, timeout)
 
 
 def make_ep_spmv_fn(
     plan: PackPlan,
     vals: np.ndarray,
     mode: Literal["software", "streaming"] = "software",
-    timeout: float | None = None,
 ):
     """Bind a PackPlan + matrix values; return jit'd ``x -> y``.
 
-    ``plan`` must be a host-side ``PackPlan``.  Passing a service-supplied
-    handle (ServicePlan / PlanTicket) is deprecated: resolution lives in
-    the request layer (``repro.runtime.request.resolve_plan`` /
-    ``GraphServer``), which owns tenants, timeouts, and the compile cache —
-    the shim below unwraps handles with a ``DeprecationWarning`` so old
-    callers keep working.  The ``timeout`` kwarg only ever applied to that
-    deprecated ticket wait and is deprecated with it.
+    ``plan`` is a host-side ``PackPlan``; service-supplied handles
+    (ServicePlan / PlanTicket) are resolved by the request layer
+    (``repro.runtime.request.resolve_plan`` / ``GraphServer``), which owns
+    tenants, timeouts, and the compile cache.
 
     The packed values and indices go to the device once, here (they change
     only when the matrix/partition changes — per paper §4 the relayout
@@ -244,24 +218,6 @@ def make_ep_spmv_fn(
     (:func:`make_bucketed_spmv_fn`): this per-plan form compiles once per
     bound plan.
     """
-    if timeout is not None:
-        warnings.warn(
-            "make_ep_spmv_fn(timeout=...) is deprecated: pass timeouts to "
-            "the request layer (GraphRequest.timeout / resolve_plan)",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-    if not isinstance(plan, PackPlan):
-        warnings.warn(
-            "passing a ServicePlan/PlanTicket to make_ep_spmv_fn is "
-            "deprecated; resolve it first via "
-            "repro.runtime.request.resolve_plan",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        from ..runtime.request import resolve_plan as _resolve  # lazy: layering
-
-        plan = _resolve(plan, timeout)
     # The plan's own tiles as a batch-of-1 bucket: the same kernel and
     # combine as the bucketed path, with the packed operands uploaded once
     # and passed as arguments (never folded into the program as constants).

@@ -1,8 +1,4 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers.
-
-NOTE: ``repro.launch.dryrun`` sets xla_force_host_platform_device_count=512
-at import (before jax init) — import it only in dry-run processes.
-"""
+"""Launchers: production mesh, train driver, HLO analysis, replica worker."""
 from .mesh import make_host_mesh, make_production_mesh
 
 __all__ = ["make_host_mesh", "make_production_mesh"]

@@ -174,8 +174,7 @@ def roofline_terms(
     """flops/bytes are whole-program HLO totals; collectives are per-chip.
 
     On an SPMD program ``cost_analysis`` already reports per-device work, so
-    callers pass chips=1 scaling there — see dryrun.py for the convention
-    actually used (documented where the numbers are produced).
+    callers pass ``chips=1`` for it.
     """
     return RooflineTerms(
         compute_s=flops / (chips * hw.peak_flops),

@@ -19,8 +19,8 @@ matching ``FaultInjector.stall_jobs`` semantics: jobs ``FIRST..LAST``
 
 :func:`spawn_worker` / :func:`spawn_process_group` are the parent-side
 helpers: spawn N workers, wrap each in a ``RemoteReplica``, and hand the
-set to ``ReplicaGroup`` — the ``--transport=process`` path of
-``launch.serve`` and the kill -9 scenario in ``benchmarks/svc_chaos.py``.
+set to ``ReplicaGroup``, so a group of worker processes serves the same
+submit surface as in-process replicas (and can be killed with -9).
 """
 from __future__ import annotations
 

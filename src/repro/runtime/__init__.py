@@ -1,5 +1,5 @@
-"""Distributed runtime: sharding rules, train/serve steps, fault tolerance,
-and the typed EP-SpMV request layer (GraphServer + bucketed compilation)."""
+"""Distributed runtime: sharding rules, train steps, fault tolerance, and
+the typed EP-SpMV request layer (GraphServer + bucketed compilation)."""
 from .fault import (
     CircuitBreaker,
     FaultTolerantLoop,
@@ -17,7 +17,6 @@ from .request import (
     ServeResult,
     resolve_plan,
 )
-from .serve import make_decode_step, make_graph_serve_fn, make_prefill_step
 from .sharding import (
     ShardingRules,
     batch_specs,
@@ -47,9 +46,6 @@ __all__ = [
     "batch_specs",
     "cache_spec_tree",
     "init_train_state",
-    "make_decode_step",
-    "make_graph_serve_fn",
-    "make_prefill_step",
     "make_sharding_rules",
     "make_train_step",
     "named",

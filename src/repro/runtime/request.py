@@ -166,7 +166,7 @@ class ServeInfo:
     degraded: bool = False
 
     def as_dict(self) -> dict:
-        """Legacy dict view — superset of the old ``(y, info)`` keys."""
+        """Plain dict view of the fields."""
         return dataclasses.asdict(self)
 
 
@@ -405,9 +405,8 @@ class _Pending:
 class GraphServer:
     """EP-SpMV request server: plan service + bucketed compiles + batching.
 
-    Owns the compiled-kernel cache (what ``make_graph_serve_fn``'s module
-    memo used to be) and the default ``tenant``/``priority`` (what the
-    ``serve.tenant`` function-attribute hack used to be).  Two lanes:
+    Owns the compiled-kernel cache and the default ``tenant``/``priority``.
+    Two lanes:
 
     * :meth:`serve` — synchronous.  The request's plan picks a shape
       bucket; the batch-of-1 runs through the bucket's shared executable
@@ -420,8 +419,7 @@ class GraphServer:
       never blocks on a cold partition.
 
     ``bucketing=None`` disables buckets entirely — every structure gets a
-    dedicated compile through the same bounded cache (the measured
-    baseline in ``benchmarks/svc_batched.py``).
+    dedicated compile through the same bounded cache.
 
     ``service`` is any object with the ``PartitionService`` submit surface —
     a single service or a ``core.replica.ReplicaGroup`` (replication with

@@ -1,6 +1,6 @@
 """Request API + bucketed compilation + micro-batched serving (GraphServer).
 
-Covers the serve-path guarantees the benchmarks gate on:
+Covers the serve-path guarantees:
   * bucket ceilings are exact at the boundary (a dim exactly at a geometric
     ceiling stays there; one past it doubles) and oversized requests fall
     back to a dedicated compile instead of crashing;
@@ -9,15 +9,13 @@ Covers the serve-path guarantees the benchmarks gate on:
   * one compiled kernel serves every structure in a bucket;
   * the compile cache evicts by (size, recency) and surfaces counters
     through ``GraphServer.metrics()``;
-  * micro-batched requests keep per-request tenant/batch attribution;
-  * the deprecated shims (tuple serve fn, kernels.resolve_plan, ServicePlan
-    into make_ep_spmv_fn, the timeout kwarg) warn but keep working.
+  * micro-batched requests keep per-request tenant/batch attribution.
 """
 import numpy as np
 import pytest
 
 from repro.core import PartitionService, PlanPadding, synthetic_bipartite_graph
-from repro.kernels import make_ep_spmv_fn, pad_plan_operands
+from repro.kernels import pad_plan_operands
 from repro.runtime import (
     BucketKey,
     BucketPolicy,
@@ -192,6 +190,24 @@ class TestGraphServerServe:
         assert stats["misses"] == 1 and stats["hits"] >= 1
         assert len(stats["buckets"]) == 1
 
+    def test_family_sweep_compiles_once_per_bucket(self, service):
+        # Four shape families of small graphs, two structures each, at k=8:
+        # each family fills its own bucket, every bucket compiles once, and
+        # every bucketed answer is byte-identical to a dedicated compile.
+        families = [(150, 150, 4), (150, 150, 16), (300, 300, 5), (300, 300, 3)]
+        bucketed = GraphServer(service, k=8, start_batcher=False)
+        dedicated = GraphServer(service, k=8, bucketing=None,
+                                start_batcher=False)
+        for fam, (n_rows, n_cols, npr) in enumerate(families):
+            for g in range(2):
+                req = _entry(n_rows, n_cols, npr, seed=1000 * fam + g)
+                y_b = np.asarray(bucketed.serve(req).y)
+                assert np.array_equal(y_b, np.asarray(dedicated.serve(req).y))
+        stats = bucketed.stats()
+        assert len(stats["buckets"]) == len(families)
+        assert stats["misses"] <= len(stats["buckets"]) + 1
+        assert stats["hits"] == 2 * len(families) - stats["misses"]
+
     def test_exactly_at_ceiling_request_serves(self, service):
         server = GraphServer(service, k=4, pad=8, start_batcher=False)
         req = _entry(256, 256, 3, seed=0)  # n_rows/n_cols exactly at floor
@@ -333,32 +349,3 @@ class TestBrownout:
             assert stats["brownout_rejects"] >= 1
             assert stats["brownout_level"] == 2
 
-
-class TestDeprecatedShims:
-    def test_make_graph_serve_fn_warns_but_serves(self, service):
-        from repro.runtime import make_graph_serve_fn
-
-        with pytest.warns(DeprecationWarning):
-            serve = make_graph_serve_fn(service, k=4, pad=8)
-        req = _entry(64, 64, 3, seed=0)
-        y, info = serve(req.n_rows, req.n_cols, req.rows, req.cols,
-                        req.vals, req.x)
-        assert isinstance(info, dict) and "cache_hit" in info
-        np.testing.assert_allclose(np.asarray(y), _ref(req),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_kernels_resolve_plan_forwarder_warns(self, service):
-        from repro.kernels import resolve_plan
-
-        _, rows, cols = synthetic_bipartite_graph(64, 64, 3, seed=0)
-        sp = service.get_spmv_plan(64, 64, rows, cols, k=4, pad=8)
-        with pytest.warns(DeprecationWarning):
-            plan = resolve_plan(sp)
-        assert plan is sp.plan
-
-    def test_timeout_kwarg_warns(self, service):
-        _, rows, cols = synthetic_bipartite_graph(64, 64, 3, seed=0)
-        sp = service.get_spmv_plan(64, 64, rows, cols, k=4, pad=8)
-        vals = np.ones(rows.shape[0], dtype=np.float32)
-        with pytest.warns(DeprecationWarning):
-            make_ep_spmv_fn(sp.plan, vals, timeout=1.0)

@@ -535,10 +535,7 @@ class TestServicePlanKernel:
         rng = np.random.default_rng(0)
         vals = rng.standard_normal(rows.shape[0]).astype(np.float32)
         x = rng.standard_normal(n_cols).astype(np.float32)
-        # ServicePlan directly: deprecated shim, still resolves but warns.
-        with pytest.warns(DeprecationWarning):
-            fn = make_ep_spmv_fn(sp, vals, mode="software")
-        y = fn(jnp.asarray(x))
+        y = make_ep_spmv_fn(sp.plan, vals, mode="software")(jnp.asarray(x))
         ref = spmv_coo_ref(n_rows, jnp.asarray(rows), jnp.asarray(cols),
                            jnp.asarray(vals), jnp.asarray(x))
         np.testing.assert_allclose(np.asarray(y), np.asarray(ref), rtol=1e-5, atol=1e-5)
@@ -581,18 +578,19 @@ class TestServicePlanKernel:
         import jax.numpy as jnp
 
         from repro.kernels.ref import spmv_coo_ref
-        from repro.runtime import make_graph_serve_fn
+        from repro.runtime import GraphRequest, GraphServer
 
         n_rows = n_cols = 64
         _, rows, cols = synthetic_bipartite_graph(n_rows, n_cols, 3, seed=4)
-        serve = make_graph_serve_fn(service, k=4, pad=8)
+        server = GraphServer(service, k=4, pad=8, start_batcher=False)
         rng = np.random.default_rng(2)
         x = rng.standard_normal(n_cols).astype(np.float32)
         vals_a = rng.standard_normal(rows.shape[0]).astype(np.float32)
         vals_b = rng.standard_normal(rows.shape[0]).astype(np.float32)
-        y_a, info_a = serve(n_rows, n_cols, rows, cols, vals_a, x)
-        y_b, info_b = serve(n_rows, n_cols, rows, cols, vals_b, x)
-        assert not info_a["cache_hit"] and info_b["cache_hit"]
+        res_a = server.serve(GraphRequest(n_rows, n_cols, rows, cols, vals_a, x))
+        res_b = server.serve(GraphRequest(n_rows, n_cols, rows, cols, vals_b, x))
+        y_a, y_b = res_a.y, res_b.y
+        assert not res_a.info.cache_hit and res_b.info.cache_hit
         # Same structure, new values: the kernel must serve B's values, not A's.
         ref_b = spmv_coo_ref(n_rows, jnp.asarray(rows), jnp.asarray(cols),
                              jnp.asarray(vals_b), jnp.asarray(x))
@@ -670,6 +668,36 @@ class TestMultiTenant:
             assert snap.tenants["victim"]["evictions"] == 0
             assert snap.tenants["flooder"]["evictions"] >= 4
             assert snap.tenants["victim"]["hits"] == 1
+
+    def test_budgeted_victims_stay_warm_across_flood_rounds(self):
+        """Three victims re-request their hot graph after every burst of a
+        flooder wider than the whole cache, on a two-worker pool: with
+        per-tenant budgets of 2.5 hot plans, every victim request after
+        warm-up is a cache hit and the flood evicts only its own plans."""
+        k, rounds = 64, 5
+        victims = {
+            f"victim{i}": synthetic_powerlaw_graph(1000, 4000, alpha=2.1 + 0.1 * i,
+                                                   seed=100 + i)
+            for i in range(3)
+        }
+        flood = [synthetic_powerlaw_graph(1000, 4000, seed=200 + i) for i in range(12)]
+        with PartitionService() as probe:
+            budget = int(probe.get(victims["victim0"], k).nbytes() * 2.5)
+        with PartitionService(workers=2, default_tenant_budget=budget) as svc:
+            for name, g in victims.items():
+                svc.get(g, k, tenant=name)
+            for _ in range(rounds):
+                for g in flood:
+                    svc.get(g, k, tenant="flooder", priority=-1)
+                for name, g in victims.items():
+                    ticket = svc.submit(g, k, tenant=name, priority=1)
+                    ticket.result(timeout=120)
+                    assert ticket.cache_hit, name
+            snap = svc.metrics()
+        for name in victims:
+            assert snap.tenants[name]["evictions"] == 0
+            assert snap.tenants[name]["hits"] == rounds
+        assert snap.tenants["flooder"]["evictions"] > 0
 
     def test_lineage_pinned_base_survives_own_tenant_flood(self):
         base_graph = synthetic_powerlaw_graph(600, 2400, seed=1)
@@ -822,6 +850,24 @@ class TestSchedulerThroughService:
             plans = [t.result(timeout=120) for t in tickets]
             assert len({p.fingerprint for p in plans}) == 4
             assert svc.stats.full_runs == 4
+
+    def test_process_executor_service_runs_on_a_process_pool(self):
+        """``executor="process"`` really moves cold plans onto a pool of
+        spawned processes, and their plans are byte-identical to the
+        in-process worker's."""
+        import multiprocessing.pool
+
+        graphs = [synthetic_powerlaw_graph(400, 1600, seed=90 + i) for i in range(2)]
+        with PartitionService() as ref:
+            expected = [ref.get(g, 8) for g in graphs]
+        with PartitionService(workers=2, executor="process") as svc:
+            assert isinstance(svc.scheduler._pool, multiprocessing.pool.Pool)
+            tickets = [svc.submit(g, 8) for g in graphs]
+            plans = [t.result(timeout=120) for t in tickets]
+            assert svc.metrics().executor == "process"
+        for got, want in zip(plans, expected):
+            assert got.fingerprint == want.fingerprint
+            np.testing.assert_array_equal(got.result.labels, want.result.labels)
 
     def test_metrics_snapshot_through_service(self, service):
         e = synthetic_mesh_graph(16, seed=5)

@@ -23,6 +23,7 @@ from repro.core import (
     ServiceClosedError,
     affinity_graph_from_coo,
     synthetic_mesh_graph,
+    synthetic_powerlaw_graph,
     synthetic_random_graph,
 )
 from repro.runtime.request import GraphRequest, GraphServer
@@ -142,6 +143,29 @@ class TestFailover:
             row = next(r for r in rm.replicas if r.replica == primary)
             assert row.state == "crashed" and row.weight == 0.0
             assert row.failovers_from >= 1
+
+    def test_failover_stream_byte_identical_to_fault_free_run(self):
+        """A three-tenant stream; the primary crashes after two completed
+        jobs while stalled work is mid-V-cycle.  No ticket is lost, and
+        every response is byte-identical to a fault-free run's."""
+        graphs = [synthetic_powerlaw_graph(200, 800, seed=400 + i) for i in range(10)]
+        tenants = ("tenant-a", "tenant-b", "tenant-c")
+        with PartitionService() as ref:
+            expected = [ref.get(e, 16) for e in graphs]
+        inj = (FaultInjector(seed=0)
+               .stall_jobs("r0", 0.15, first=0, last=3)
+               .stall_jobs("r1", 0.15, first=0, last=3)
+               .crash_after_jobs("r1", 2))
+        with ReplicaGroup(2, injector=inj, hedge=False) as g:
+            tickets = [g.submit(e, 16, tenant=tenants[i % len(tenants)])
+                       for i, e in enumerate(graphs)]
+            plans = [t.result(60) for t in tickets]
+            rm = g.replica_metrics()
+        assert any(e[0] == "crash" for e in inj.events)
+        assert rm.lost == 0 and rm.failovers >= 1
+        for got, want in zip(plans, expected):
+            assert got.fingerprint == want.fingerprint
+            np.testing.assert_array_equal(got.result.labels, want.result.labels)
 
     def test_queued_tickets_on_killed_replica_fail_over(self):
         """kill() drains the dead replica's queue (ServiceClosedError),

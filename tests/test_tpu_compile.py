@@ -57,7 +57,7 @@ def _compiled_text(spec: BucketSpec, sharding) -> str:
 
 @pytest.mark.parametrize("mode", ["software", "streaming"])
 def test_svc_batched_bucket(one_chip, mode):
-    # The (512, 512, 2048) bucket of benchmarks/svc_batched.py: k=8, batch 8.
+    # The (512, 512, 2048) bucket of chip_smoke.py's small-graph families: k=8, batch 8.
     spec = BucketKey(n_rows=512, n_cols=512, nnz=2048, k=8, mode=mode).spec(batch=8)
     assert "tpu_custom_call" in _compiled_text(spec, one_chip)
 

@@ -282,15 +282,38 @@ class TestOverloadOverWire:
 
     def test_worker_process_surfaces_rejection(self):
         """Across a real process boundary: a worker spawned with a queue
-        bound answers the typed rejection through spawn_worker's wire."""
+        bound answers the typed rejection through spawn_worker's wire, with
+        the same constructor args (tenant, slot accounting, retry hint) as
+        the rejection an in-process service raises in the same state."""
+        import threading
+
         from repro.core import AdmissionRejectedError
+
+        graphs = [synthetic_mesh_graph(14 + 2 * i, seed=50 + i)
+                  for i in range(3)]
+        svc = PartitionService(workers=1, max_queue_depth=1)
+        gate = threading.Event()
+        started = threading.Event()
+
+        def hook(_key):
+            started.set()
+            gate.wait(10)
+
+        svc.scheduler.pre_job_hook = hook
+        try:
+            svc.submit(graphs[0], 4)  # picked up: stalls in the hook
+            assert started.wait(10)
+            svc.submit(graphs[1], 4)  # queued: holds the single slot
+            with pytest.raises(AdmissionRejectedError) as local:
+                svc.submit(graphs[2], 4)
+        finally:
+            gate.set()
+            svc.close()
 
         h = spawn_worker(queue_bound=1, stalls=[(1.0, 0, 1 << 30)])
         rep = RemoteReplica(h.address, process=h.proc, pid=h.pid)
         try:
             assert _wait(rep.heartbeat, 10)
-            graphs = [synthetic_mesh_graph(14 + 2 * i, seed=50 + i)
-                      for i in range(3)]
             rep.submit(graphs[0], 4)  # picked up: sits in the 1s stall
             time.sleep(0.25)          # let the worker reach the stall
             rep.submit(graphs[1], 4)  # queued: holds the single slot
@@ -302,6 +325,8 @@ class TestOverloadOverWire:
         finally:
             rep.close()
         assert h.proc.poll() is not None
+        assert (pickle.dumps(ei.value.__reduce__()[1])
+                == pickle.dumps(local.value.__reduce__()[1]))
 
 
 class TestWorkerProcess:
@@ -342,7 +367,15 @@ class TestWorkerProcess:
                        for i, e in enumerate(graphs)]
             plans = [t.result(180) for t in tickets]
             assert all(sp is not None and sp.fingerprint for sp in plans)
+            assert g.replica_metrics().lost == 0
             # Six distinct graphs -> six distinct plans, none served stale.
             assert len({sp.fingerprint for sp in plans}) == len(plans)
             assert not any(t.stale for t in tickets)
             assert any(e[0] == "sigkill" for e in inj.events)
+        # Crossing the wire and losing a worker changes no byte: every plan
+        # matches the fault-free in-process one.
+        with PartitionService(workers=1) as local:
+            for e, sp in zip(graphs, plans):
+                ref = local.get(e, 4)
+                assert sp.fingerprint == ref.fingerprint
+                np.testing.assert_array_equal(sp.result.labels, ref.result.labels)
