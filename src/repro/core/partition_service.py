@@ -52,12 +52,11 @@ import hashlib
 import os
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import numpy as np
 
-from . import tracing
+from . import digest, tracing
 from .edge_partition import EdgePartitionResult, edge_partition
 from .graph import EdgeList, affinity_graph_from_coo, csr_from_edges
 from .metrics import evaluate_edge_partition
@@ -138,74 +137,10 @@ def graph_fingerprint(
         return h.hexdigest()
 
 
-# The SpMV plan key is a sha256 tree: fixed 1 MiB leaves of ``rows`` and
-# ``cols``, each hashed on its own, then a root over the leaf digests, so
-# the leaves of a large COO hash on several cores (hashlib releases the GIL
-# for buffers over 2 KiB).  The leaf size and prefixes are part of the key.
-_LEAF_BYTES = 1 << 20
+# The SpMV plan key is a sha256 tree (:mod:`.digest`) over ``rows`` and
+# ``cols``; its prefixes, meta and leaf size are part of the key.
 _LEAF_PREFIX = b"repro.spmv_plan_key.leaf\x00"
 _ROOT_PREFIX = b"repro.spmv_plan_key.root\x00"
-_TREE_TAG = "sha256-tree"
-# At most this many leaves are hashed on the calling thread.
-_INLINE_LEAVES = 2
-# Thread cap: over 45.8 MB on a 13-core TPU v5e host, 1 thread took 33.4 ms,
-# 8 took 5.18, 13 took 4.07 and 16 took 3.87; 16 is the fewest within 5%.
-_MAX_HASH_THREADS = 16
-
-_hash_pool: Optional[ThreadPoolExecutor] = None
-_hash_pool_lock = threading.Lock()
-
-
-def _leaf_pool() -> ThreadPoolExecutor:
-    """The process's leaf-hashing pool, made on first use: one thread per
-    usable core, at most :data:`_MAX_HASH_THREADS`."""
-    global _hash_pool
-    with _hash_pool_lock:
-        if _hash_pool is None:
-            try:
-                cores = len(os.sched_getaffinity(0))
-            except AttributeError:
-                cores = os.cpu_count() or 1
-            _hash_pool = ThreadPoolExecutor(
-                max_workers=min(cores, _MAX_HASH_THREADS),
-                thread_name_prefix="plan-key")
-        return _hash_pool
-
-
-def _forget_leaf_pool() -> None:
-    # A forked child has none of the parent's threads: it makes its own.
-    global _hash_pool, _hash_pool_lock
-    _hash_pool = None
-    _hash_pool_lock = threading.Lock()
-
-
-os.register_at_fork(after_in_child=_forget_leaf_pool)
-
-
-def _leaf_digest(leaf: memoryview) -> bytes:
-    h = hashlib.sha256(_LEAF_PREFIX)
-    h.update(leaf)
-    return h.digest()
-
-
-def _sha256_tree(meta: tuple, *arrays: np.ndarray) -> str:
-    """sha256 over the root prefix, ``repr(meta)`` and the digests, in
-    order, of each array's 1 MiB leaves (contiguous, hashed in place).  The
-    value depends on the content alone, not on where the leaves hash."""
-    leaves = []
-    for a in arrays:
-        buf = memoryview(a).cast("B")
-        leaves += [buf[i:i + _LEAF_BYTES] for i in range(0, len(buf), _LEAF_BYTES)]
-    if len(leaves) <= _INLINE_LEAVES:
-        digests = map(_leaf_digest, leaves)
-    else:
-        digests = _leaf_pool().map(_leaf_digest, leaves)
-        tracing.count("plan.fingerprint_pooled", 1)
-    h = hashlib.sha256(_ROOT_PREFIX)
-    h.update(repr(meta).encode())
-    for d in digests:
-        h.update(d)
-    return h.hexdigest()
 
 
 def spmv_plan_key(
@@ -226,7 +161,7 @@ def spmv_plan_key(
     hit needs no graph at all.  Contiguous int64 ``rows``/``cols`` are
     hashed in place through memoryviews (anything else is converted
     first), after the shapes and options, as a sha256 tree of 1 MiB leaves
-    (:func:`_sha256_tree`): a full 256-bit content digest, so a caller
+    (:func:`.digest.sha256_tree`): a full 256-bit content digest, so a caller
     that edits its arrays in place gets a new key.  Keys of more than two
     leaves hash them on a process-wide thread pool.
     """
@@ -236,10 +171,12 @@ def spmv_plan_key(
         if rows.shape != cols.shape:
             raise ValueError("rows and cols must have identical shapes")
         meta = ("spmv", n_rows, n_cols, rows.size, k, pad, method, seed,
-                _TREE_TAG, _LEAF_BYTES)
+                digest.TREE_TAG, digest.LEAF_BYTES)
         if opts is not None:
             meta = meta + dataclasses.astuple(opts)
-        key = _sha256_tree(meta, rows, cols)
+        key, pooled = digest.sha256_tree(_LEAF_PREFIX, _ROOT_PREFIX, meta, rows, cols)
+        if pooled:
+            tracing.count("plan.fingerprint_pooled", 1)
         tracing.count("plan.fingerprint_bytes", rows.nbytes + cols.nbytes)
         return key
 

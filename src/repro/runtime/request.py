@@ -31,7 +31,6 @@ compile).  ``GraphServer.submit`` is the queued lane that trades up to
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import math
 import threading
 import time
@@ -41,7 +40,7 @@ from typing import Any, Callable, Optional
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import tracing
+from ..core import digest, tracing
 from ..core.partition_service import (
     AdmissionRejectedError,
     PartitionService,
@@ -105,6 +104,10 @@ def resolve_plan(plan, timeout: float | None = None) -> PackPlan:
 # ---------------------------------------------------------------------------
 
 
+_VALS_LEAF_PREFIX = b"repro.vals_digest.leaf\x00"
+_VALS_ROOT_PREFIX = b"repro.vals_digest.root\x00"
+
+
 @dataclasses.dataclass
 class GraphRequest:
     """One EP-SpMV serving request: matrix structure + values + input.
@@ -137,9 +140,20 @@ class GraphRequest:
             raise ValueError("rows/cols/vals must have identical shapes")
 
     def vals_digest(self) -> str:
-        return hashlib.blake2b(
-            np.ascontiguousarray(self.vals).tobytes(), digest_size=16
-        ).hexdigest()
+        """Content digest of the float32 ``vals``, which keys the compiled
+        dedicated program and a bucket's staged operands: a sha256 tree of
+        1 MiB leaves hashed in place (:func:`repro.core.digest.sha256_tree`),
+        on the thread pool above two leaves, so any edited value is a new
+        digest."""
+        with tracing.span("serve.vals_digest"):
+            vals = np.ascontiguousarray(self.vals, dtype=np.float32)
+            meta = ("vals", "float32", vals.size, digest.TREE_TAG, digest.LEAF_BYTES)
+            key, pooled = digest.sha256_tree(_VALS_LEAF_PREFIX, _VALS_ROOT_PREFIX,
+                                             meta, vals)
+            if pooled:
+                tracing.count("serve.vals_digest_pooled", 1)
+            tracing.count("serve.vals_digest_bytes", vals.nbytes)
+            return key
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,6 +1,7 @@
 """The SpMV plan key (``spmv_plan_key``): one content key over the request's
 own COO, the same on every SpMV path, and a warm serve that never builds
-the affinity graph."""
+the affinity graph; and the value digest (``GraphRequest.vals_digest``), a
+tree of the same kind over the request's values."""
 import dataclasses
 import hashlib
 import sys
@@ -14,6 +15,7 @@ from repro.core import (
     AdmissionRejectedError,
     PartitionService,
     ReplicaGroup,
+    digest,
     spmv_plan_key,
     synthetic_bipartite_graph,
 )
@@ -259,3 +261,82 @@ def test_int32_and_strided_inputs_give_the_int64_key(nnz):
     assert not s_rows.flags.c_contiguous and not s_cols.flags.c_contiguous
     assert spmv_plan_key(n, n, s_rows, s_cols, K, PAD) == key
     assert spmv_plan_key(n, n, rows.astype(np.int32), cols.astype(np.int32), K, PAD) == key
+
+
+# -- the value digest's sha256 tree -------------------------------------------
+
+VALS_LEAF_ENTRIES = LEAF // 4  # float32
+
+
+def _serial_vals_digest(vals):
+    """The value digest built leaf by leaf on this thread, from its definition."""
+    raw = np.ascontiguousarray(vals, dtype=np.float32).tobytes()
+    meta = ("vals", "float32", len(raw) // 4, "sha256-tree", LEAF)
+    root = hashlib.sha256(b"repro.vals_digest.root\x00" + repr(meta).encode())
+    for i in range(0, len(raw), LEAF):
+        root.update(hashlib.sha256(b"repro.vals_digest.leaf\x00" + raw[i:i + LEAF]).digest())
+    return root.hexdigest()
+
+
+def _vals_request(nnz, seed=0):
+    """A request whose digest is all that is asked of it: one row, one column."""
+    zeros = np.zeros(nnz, np.int64)
+    vals = np.random.default_rng(seed).standard_normal(nnz)
+    return GraphRequest(1, 1, zeros, zeros, vals, np.zeros(1))
+
+
+@pytest.mark.parametrize("nnz", [0, VALS_LEAF_ENTRIES, VALS_LEAF_ENTRIES + 1,
+                                 3 * VALS_LEAF_ENTRIES + 5],
+                         ids=["empty", "one_leaf", "one_leaf_plus_one", "four_leaves"])
+def test_vals_digest_equals_the_serial_tree(nnz):
+    req = _vals_request(nnz, seed=nnz)
+    assert req.vals_digest() == _serial_vals_digest(req.vals)
+
+
+@pytest.mark.parametrize("leaf", ["first", "middle", "last"])
+def test_an_edit_in_any_leaf_changes_the_vals_digest(leaf):
+    req = _vals_request(3 * VALS_LEAF_ENTRIES, seed=12)
+    before = req.vals_digest()
+    i = {"first": 3, "middle": VALS_LEAF_ENTRIES + 17, "last": req.vals.size - 1}[leaf]
+    req.vals[i] = np.nextafter(req.vals[i], np.float32(np.inf))
+    assert req.vals_digest() != before
+
+
+def test_vals_digests_from_many_threads_equal_the_serial_ones():
+    reqs = [_vals_request(3 * VALS_LEAF_ENTRIES + 1000 * t, seed=40 + t) for t in range(8)]
+    want = [_serial_vals_digest(r.vals) for r in reqs]
+    got = [[] for _ in reqs]
+
+    def work(t):
+        for _ in range(4):
+            got[t].append(reqs[t].vals_digest())
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(t,)) for t in range(len(reqs))]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    assert got == [[w] * 4 for w in want]
+
+
+@pytest.mark.parametrize("nnz", [N, 2 * LEAF_ENTRIES], ids=["inline", "pooled"])
+def test_vals_digest_differs_from_the_plan_key_over_the_same_bytes(nnz):
+    rows, cols = _big_coo(nnz, seed=50)
+    n = 1 << 20
+    # vals whose bytes are those of rows then cols, leaf for leaf.
+    same = np.concatenate([rows, cols]).view(np.float32)
+    req = GraphRequest(1, 1, np.zeros(same.size, np.int64), np.zeros(same.size, np.int64),
+                       same, np.zeros(1))
+    assert req.vals.tobytes() == rows.tobytes() + cols.tobytes()
+    assert req.vals_digest() != spmv_plan_key(n, n, rows, cols, K, PAD)
+    # The prefixes alone keep the trees apart, even under one meta.
+    meta = ("vals", "float32", same.size, "sha256-tree", LEAF)
+    key_tree, _ = digest.sha256_tree(ps._LEAF_PREFIX, ps._ROOT_PREFIX, meta, req.vals)
+    assert req.vals_digest() != key_tree
+

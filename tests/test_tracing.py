@@ -166,3 +166,35 @@ def test_submit_records_the_queue_wait(service):
     assert wait[1] == handle.t_enqueue and wait[2] >= wait[1]
     # Bucket choice on the caller's thread, staging on the batcher's.
     assert len(rec["serve.launch"]) == 2
+
+
+@pytest.mark.parametrize("size", ["inline", "pooled"])
+def test_a_serve_counts_the_value_digests_bytes_and_pooled_leaves(service, size):
+    # 135,168 rows of degree ~4: more than two 1 MiB leaves of float32 vals.
+    req = _request(96 if size == "inline" else 135168, seed=6)
+    leaves = -(-req.vals.nbytes // (1 << 20))
+    assert (leaves >= 3) == (size == "pooled")
+    key_leaves = 2 * -(-req.rows.nbytes // (1 << 20))
+    server = GraphServer(service, k=4, pad=8, start_batcher=False, bucketing=None)
+    before = server.stats()["spans"]
+    lo = time.perf_counter()
+    server.serve(req)
+    hi = time.perf_counter()
+    after = server.stats()["spans"]
+
+    def added(name):
+        was = before.get(name, {"count": 0, "total": 0.0})
+        now = after.get(name, {"count": 0, "total": 0.0})
+        return now["count"] - was["count"], now["total"] - was["total"]
+
+    (digest,) = tracing.records("serve.vals_digest", lo, hi)
+    (launch,) = tracing.records("serve.launch", lo, hi)
+    assert launch[1] <= digest[1] <= digest[2] <= launch[2]
+    assert added("serve.vals_digest")[0] == 1
+    assert added("serve.vals_digest_bytes") == (1, req.vals.nbytes)
+    pooled = 1 if size == "pooled" else 0
+    assert added("serve.vals_digest_pooled") == (pooled, pooled)
+    # The plan key's pooled counter counts the plan key alone.
+    key_pooled = 1 if key_leaves >= 3 else 0
+    assert added("plan.fingerprint_pooled") == (key_pooled, key_pooled)
+    assert {"serve.vals_digest", "serve.vals_digest_bytes"} <= after.keys()
