@@ -12,6 +12,11 @@ program and the CG update, and runs one iteration of each kind.  A reservoir
 of ``check_sample`` iterations, drawn from the seed, keeps each one's ``x``
 and served ``y`` for the comparison after the window.
 
+A driver for another operator subclasses :class:`Driver` and overrides its
+three hooks: ``matrix()`` (the configuration's operator), ``right_hand_side(rng)``
+(one right-hand side from the seed's generator) and ``xla_rescale()`` (the
+factor that keeps the XLA baseline's repeated products in range).
+
 Traffic keys: ``iterations_per_set``, ``rhs_pool``, ``check_sample``.
 """
 from __future__ import annotations
@@ -53,14 +58,30 @@ class Driver:
         self.start = jax.jit(bench_cg_start)
         self.step = jax.jit(bench_cg_step)
 
+    def matrix(self) -> tuple[int, np.ndarray, np.ndarray, np.ndarray]:
+        """``(n, rows, cols, vals)`` of the configuration's operator: HPCG's
+        27-point stencil on the ``nx × ny × nz`` grid."""
+        c = self.cfg
+        n, rows, cols = stencil.coo(c["nx"], c["ny"], c["nz"])
+        return n, rows, cols, stencil.hpcg_values(rows, cols)
+
+    def right_hand_side(self, rng: np.random.Generator) -> np.ndarray:
+        """``b = A x*`` with ``x*`` standard normal from ``rng``."""
+        x_star = rng.standard_normal(self.n).astype(np.float32)
+        return reference.spmv(self.n, self.rows, self.cols, self.vals, x_star)
+
+    def xla_rescale(self) -> float:
+        """The stencil's diagonal is 26: this keeps the XLA baseline's
+        repeated products in range."""
+        return 1.0 / stencil.DIAGONAL
+
     def setup(self) -> None:
         from repro.core import PartitionService
         from repro.runtime import GraphRequest, GraphServer
 
         self.GraphRequest = GraphRequest
         c = self.cfg
-        self.n, self.rows, self.cols = stencil.coo(c["nx"], c["ny"], c["nz"])
-        self.vals = stencil.hpcg_values(self.rows, self.cols)
+        self.n, self.rows, self.cols, self.vals = self.matrix()
         self.svc = PartitionService(max_entries=c["plan_cache_entries"])
         self.server = GraphServer(self.svc, k=c["k"], max_batch=c["max_batch"],
                                   compile_cache_entries=c["compile_cache_entries"],
@@ -94,12 +115,8 @@ class Driver:
 
     def prepare(self, seed: int, seconds: float) -> None:
         rng = np.random.default_rng(seed)
-        pool = int(self.traffic["rhs_pool"])
-        self.rhs = []
-        for _ in range(pool):
-            x_star = rng.standard_normal(self.n).astype(np.float32)
-            b = reference.spmv(self.n, self.rows, self.cols, self.vals, x_star)
-            self.rhs.append(jax.device_put(b.astype(np.float32)))
+        self.rhs = [jax.device_put(self.right_hand_side(rng).astype(np.float32))
+                    for _ in range(int(self.traffic["rhs_pool"]))]
         jax.block_until_ready(self.rhs)
         self.sample_rng = np.random.default_rng([seed, 1])
         self.sample_size = int(self.traffic["check_sample"])
@@ -168,13 +185,12 @@ class Driver:
         rows = jnp.asarray(self.rows, jnp.int32)
         cols = jnp.asarray(self.cols, jnp.int32)
         vals = jnp.asarray(self.vals)
-
-        n = self.n
+        n, scale = self.n, self.xla_rescale()
 
         @jax.jit
         def bench_xla_spmv(rows, cols, vals, x):
             y = jnp.zeros(n, jnp.float32).at[rows].add(vals * x[cols])
-            return y * (1.0 / 26.0)  # keeps repeated products in range
+            return y * scale
 
         x = jnp.ones(n, jnp.float32)
         bench_xla_spmv(rows, cols, vals, x).block_until_ready()

@@ -4,7 +4,9 @@ the result line.
 Everything that belongs to one configuration, traffic mix or metric is a
 file found by its name:
 
-* ``configs[i].file`` — the deployment's sizes and service settings;
+* ``configs[i].file`` — the deployment's sizes and service settings, and
+  under ``tiny`` the overrides that CPU tests apply to them (:func:`tiny`);
+  chip runs never read ``tiny``;
 * ``bench/traffic/<traffic>.json`` — the traffic mix's parameters; its
   ``driver`` key names the general generator in ``bench/drivers/`` that
   reads them;
@@ -38,6 +40,7 @@ from bench import reference
 
 ROOT = Path(__file__).resolve().parents[1]
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+TINY_ITERATIONS_PER_SET = 10
 
 
 # ---------------------------------------------------------------------------
@@ -97,6 +100,16 @@ def resolve(bench: dict, name: str, root: Path = ROOT) -> Cell:
         per_layer=[m for m in bench["per_layer"] if reports(m, name)],
         root=root,
     )
+
+
+def tiny(bench: dict, name: str, root: Path = ROOT) -> Cell:
+    """The cell ``name`` at the CPU tests' size: its configuration's ``tiny``
+    overrides, and sets of ``TINY_ITERATIONS_PER_SET`` iterations.  Tests
+    only; :func:`resolve` alone makes a chip run's cell."""
+    cell = resolve(bench, name, root)
+    cell.config = {**cell.config, **cell.config["tiny"]}
+    cell.traffic = {**cell.traffic, "iterations_per_set": TINY_ITERATIONS_PER_SET}
+    return cell
 
 
 def reader(metric: str, root: Path = ROOT) -> Callable[["Run"], Optional[float]]:

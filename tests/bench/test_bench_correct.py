@@ -23,10 +23,7 @@ SEED = 2**33 + 17  # wider than 32 bits, as the check's seeds are
 
 
 def tiny(name: str) -> harness.Cell:
-    cell = harness.resolve(BENCH, name)
-    cell.config = {**cell.config, "nx": 6, "ny": 6, "nz": 5, "k": 8}
-    cell.traffic = {**cell.traffic, "iterations_per_set": 10}
-    return cell
+    return harness.tiny(BENCH, name)
 
 
 def run(cell: harness.Cell) -> dict:
@@ -69,5 +66,13 @@ def test_a_sound_run_is_correct(name):
 def test_cg_cell_fails_when_its_answers_are_wrong(monkeypatch, fault):
     break_serve(monkeypatch, fault)
     out = run(tiny("hpcg-48.cg"))
+    assert not out["correct"]
+    assert out["checks"]["max_rel_err"]["value"] > out["checks"]["max_rel_err"]["limit"]
+
+
+@pytest.mark.parametrize("fault", [altered, unchanged], ids=lambda f: f.__name__)
+def test_ppr_cell_fails_when_its_answers_are_wrong(monkeypatch, fault):
+    break_serve(monkeypatch, fault)
+    out = run(tiny("graph500-16.ppr_cg"))
     assert not out["correct"]
     assert out["checks"]["max_rel_err"]["value"] > out["checks"]["max_rel_err"]["limit"]

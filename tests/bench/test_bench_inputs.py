@@ -1,13 +1,24 @@
-"""The benchmark's copied generator and its float64 reference."""
+"""The benchmark's copied generator, the drivers' right-hand sides, and the
+float64 reference."""
 from __future__ import annotations
 
+import hashlib
 import json
 
 import numpy as np
 import pytest
 
-from bench import reference
+from bench import harness, reference
 from bench.gen import stencil
+
+RHS_SEED = 2**33 + 41
+# sha256 of each cell's right-hand-side pool (float32, in pool order) at its
+# tiny size from RHS_SEED, as the drivers drew it before cg.Driver had its
+# matrix hooks: a refactor of the drivers keeps every seed's inputs.
+RHS_POOL_SHA256 = {
+    "hpcg-48.cg": "fd61a0d1bb351e25d70cf3c6a34a8f68c5a383c4432ecf232cddaf269b0f47bc",
+    "graph500-16.ppr_cg": "be19368c461aa0a34c6d2acab5ae7d0ef495f4852cc1720116e1ab49c0ba1ae2",
+}
 
 
 def test_hpcg_48_has_the_stated_rows_and_nonzeros():
@@ -62,3 +73,15 @@ def test_control_reads_far_above_float32():
     x = rng.standard_normal(n).astype(np.float32)
     ctrl = reference.control_spmv(n, rows, cols, vals, x)
     assert reference.rel_err(ctrl, n, rows, cols, vals, x) > 1e-3
+
+
+@pytest.mark.parametrize("name", sorted(RHS_POOL_SHA256))
+def test_a_seed_draws_the_right_hand_sides_and_samples_it_always_drew(name):
+    cell = harness.tiny(harness.load_benchmark(), name)
+    drv = cell.driver.Driver(cell, harness.Spans())
+    drv.n, drv.rows, drv.cols, drv.vals = drv.matrix()
+    drv.prepare(RHS_SEED, 1.0)
+    assert len(drv.rhs) == cell.traffic["rhs_pool"]
+    pool = b"".join(np.asarray(b, np.float32).tobytes() for b in drv.rhs)
+    assert hashlib.sha256(pool).hexdigest() == RHS_POOL_SHA256[name]
+    assert [int(drv.sample_rng.integers(0, 1000)) for _ in range(3)] == [389, 399, 816]

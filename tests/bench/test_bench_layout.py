@@ -78,6 +78,19 @@ def test_configs_name_every_cut_and_live_under_paths():
         assert any(w["config"] == c["name"] for w in BENCH["workloads"])
 
 
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_each_configuration_carries_its_cpu_test_sizes(config):
+    """CPU tests run a cell at its configuration's ``tiny`` sizes: a
+    configuration without them would run at full size in tier-1."""
+    with open(harness.ROOT / config["file"]) as f:
+        cfg = json.load(f)
+    tiny = cfg["tiny"]
+    assert "k" in tiny
+    for key, value in tiny.items():
+        assert key in cfg and key != "tiny", key
+        assert 0 < value <= cfg[key], key
+
+
 def test_command_and_run_length_fit_the_check():
     cmd = BENCH["command"]
     assert cmd[1].startswith("bench/") and (harness.ROOT / cmd[1]).is_file()

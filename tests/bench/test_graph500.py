@@ -112,10 +112,11 @@ def test_cg_on_the_symmetric_form_is_personalized_pagerank():
     assert pi.sum() == pytest.approx(1.0, abs=1e-6)  # no dangling mass: isolated rows get none
 
 
+CELL = "graph500-16.ppr_cg"
+
+
 def tiny() -> harness.Cell:
-    cell = harness.resolve(harness.load_benchmark(), "graph500-16.ppr_cg")
-    cell.config = {**cell.config, "scale": 8, "k": 8}
-    return cell
+    return harness.tiny(harness.load_benchmark(), CELL)
 
 
 def run(cell: harness.Cell) -> dict:
@@ -123,9 +124,10 @@ def run(cell: harness.Cell) -> dict:
 
 
 def test_the_cell_sends_twenty_iteration_queries_from_sixteen_sources():
+    assert harness.resolve(harness.load_benchmark(), CELL).traffic == {
+        "driver": "ppr_cg", "iterations_per_set": 20, "sources_per_query": 16,
+        "rhs_pool": 4, "check_sample": 8}
     cell = tiny()
-    assert cell.traffic == {"driver": "ppr_cg", "iterations_per_set": 20,
-                            "sources_per_query": 16, "rhs_pool": 4, "check_sample": 8}
     drv = cell.driver.Driver(cell, harness.Spans())
     drv.n, _, _, _, drv.degree = kronecker.graph500_ppr(cell.config)
     drv.prepare(SEED, 1.0)

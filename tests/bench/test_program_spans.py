@@ -64,7 +64,6 @@ def _run(ring, jitter_ns=(0.0,) * ITERS, ops_in_plan=()):
 
 def test_span_and_counter_readers_sum_the_window_per_spmv(ring):
     run = _run(ring)
-    assert harness.reader("plan_affinity_ms.cg")(run) == pytest.approx(10.0)
     assert harness.reader("launch_host_ms.cg")(run) == pytest.approx(1.0)
     assert harness.reader("h2d_mb_per_spmv.cg")(run) == pytest.approx(0.442368)
     # The fixture's program recorded no fingerprint: nothing to read.
@@ -98,8 +97,8 @@ def test_a_program_without_tracing_reads_nothing(ring, monkeypatch):
     run = _run(ring)
     monkeypatch.delattr(sys.modules["repro.core"], "tracing")
     monkeypatch.setitem(sys.modules, "repro.core.tracing", None)
-    for name in ("plan_affinity_ms.cg", "plan_fingerprint_ms.cg", "launch_host_ms.cg",
-                 "h2d_mb_per_spmv.cg", "idle_in_plan_pct.cg"):
+    for name in ("plan_fingerprint_ms.cg", "launch_host_ms.cg", "h2d_mb_per_spmv.cg",
+                 "idle_in_plan_pct.cg"):
         assert harness.reader(name)(run) is None, name
 
 
